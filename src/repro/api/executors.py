@@ -69,8 +69,10 @@ class Executor:
     supports_partial_fit = False
 
     def __init__(self, config: SolverConfig, mesh=None):
+        from repro.launch.mesh import auto_axes
+
         self.config = config
-        self.mesh = mesh
+        self.mesh = None if mesh is None else auto_axes(mesh)
         self.kernel = config.make_kernel_fn()
         self.mb = config.mb_config()
         self._programs = {}      # instance-local compiled-program cache

@@ -22,8 +22,7 @@ Collectives per iteration (the roofline collective term):
 
 The step is paper-faithful (Algorithm 2 semantics identical to
 repro.core.minibatch); tests assert bit-comparable trajectories against the
-single-device implementation on a CPU mesh.  ``shard_map`` itself comes
-from :mod:`repro.core.compat` — the alias moved across JAX releases.
+single-device implementation on a CPU mesh.
 """
 from __future__ import annotations
 
@@ -32,10 +31,10 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api import keys as api_keys
-from repro.core.compat import shard_map
 from repro.core.kernel_fns import (
     KernelFn, gram_rows_fn, kernel_cross, kernel_diag,
 )
@@ -364,7 +363,7 @@ def make_dist_step(kernel: KernelFn, cfg: MBConfig, mesh: Mesh,
         local_step, mesh=mesh,
         in_specs=(state_specs, P(data_axes, None)),
         out_specs=(state_specs, info_specs),
-        check_rep=False)
+        check_vma=False)
 
 
 def _local_sample_bound(mesh: Mesh, data_axes: Sequence[str],
@@ -456,7 +455,7 @@ def make_dist_sampling_step(kernel: KernelFn, cfg: MBConfig, mesh: Mesh,
         sampled, mesh=mesh,
         in_specs=(state_specs, P(data_axes, None), P()),
         out_specs=(state_specs, info_specs),
-        check_rep=False)
+        check_vma=False)
 
 
 def _fit_distributed_impl(xb_stream, center_pts: jax.Array,
@@ -702,7 +701,7 @@ def make_cached_dist_sampling_step(base_kernel: KernelFn, x_real: jax.Array,
         cached_sampled, mesh=mesh,
         in_specs=(state_specs, cache_specs, P(data_axes, None), P()),
         out_specs=(state_specs, cache_specs, info_specs),
-        check_rep=False)
+        check_vma=False)
 
 
 def fit_distributed_cached_jit(x: jax.Array, init_idx: jax.Array,
@@ -770,7 +769,7 @@ def _predict_fn(mesh: Mesh, data_axes, treedef, loc_chunk: int):
             in_specs=([P()] * treedef.num_leaves, P(), P(), P(),
                       P(data_axes, None)),
             out_specs=P(data_axes),
-            check_rep=False))
+            check_vma=False))
         _PREDICT_FNS[key] = fn
     return fn
 
@@ -787,6 +786,9 @@ def predict_distributed(state: CenterState, x: jax.Array, xq: jax.Array,
     counts by padding.  The compiled program is cached per
     (mesh, axes, kernel structure, chunk) so repeated serving calls don't
     re-trace."""
+    from repro.launch.mesh import auto_axes
+
+    mesh = auto_axes(mesh)
     if data_axes is None:
         data_axes = tuple(a for a in mesh.axis_names if a != "model")
     data_axes = tuple(data_axes)

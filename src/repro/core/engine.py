@@ -240,10 +240,10 @@ def make_fused_restart_run(kernel: KernelFn, cfg: MBConfig, mesh: Mesh,
     (EngineResult, caches)`` with per-(restart, data-shard) tile caches
     from ``init_shard_caches(..., restarts=R)`` (``xe`` stays REAL
     coordinates — scoring resolves window ids through ``x_real``)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import distributed as D
-    from repro.core.compat import shard_map
     from repro.core.distributed import DistState
     from repro.core.kernel_fns import kernel_cross, kernel_diag
     data_axes = tuple(data_axes)
@@ -355,7 +355,7 @@ def make_fused_restart_run(kernel: KernelFn, cfg: MBConfig, mesh: Mesh,
             in_specs=(st_stacked, P(data_axes, None), P(data_axes, None),
                       P(restart_axis, None)),
             out_specs=(st_win, P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
 
         # NOTE: state0 is deliberately NOT donated — only the winning
         # lane's (k, ...) state leaves the program, so the stacked
@@ -389,7 +389,7 @@ def make_fused_restart_run(kernel: KernelFn, cfg: MBConfig, mesh: Mesh,
         in_specs=(st_stacked, cache_specs, P(data_axes, None),
                   P(data_axes, None), P(restart_axis, None)),
         out_specs=(st_win, cache_specs, P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     # the per-(restart, shard) tile caches round-trip the program with
     # identical shapes — donate them so the whole cache store updates in

@@ -46,7 +46,8 @@ def init_state(x: jax.Array, center_idx: jax.Array, kernel: KernelFn,
     """Centers start as single data points (k-means++ / random init picks
     indices), occupying slot 0 with coefficient 1."""
     k = center_idx.shape[0]
-    idx = jnp.zeros((k, window), jnp.int32).at[:, 0].set(center_idx)
+    idx = jnp.pad(center_idx.astype(jnp.int32)[:, None],
+                  ((0, 0), (0, window - 1)))
     coef = jnp.zeros((k, window), jnp.float32).at[:, 0].set(1.0)
     return CenterState(
         idx=idx,

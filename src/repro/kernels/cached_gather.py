@@ -3,22 +3,22 @@
 Computes   P[i, j] = sum_w coef[j, w] * rows[i, sup_ids[j, w]]
 where ``rows`` are the batch's Gram rows K(x_B, x) already resolved through
 the Gram tile cache (repro.cache) — so the assignment step of Algorithm 2
-performs ZERO kernel evaluations: this kernel fuses the support-column
-gather with the coefficient contraction, never materializing the
-(b, k*W) cross block in HBM.
+performs ZERO kernel evaluations, and the (b, k*W) gathered cross block is
+never materialized in HBM.
 
-TPU mapping (mirrors fused_assign.py):
-* grid = (k, b/bt, W/st); the innermost axis streams support-id tiles.
-* Each step: gather a (bt, st) sub-block out of the resident (bt, n) row
-  tile with a dynamic column take, then contract with the (st,) coefficient
-  slice into the (bt, 1) output block.
-* VMEM working set per step: bt*n (row tile) + bt*st + st floats — the row
-  tile dominates; bt=128 x n=8192 f32 = 4 MB, inside the ~16 MB budget.
+The column gather is turned into a contraction: the window coefficients
+are scattered once into a dense (k, n) weight matrix V (duplicate ids add,
+pad slots with coef == 0 add nothing), and the kernel computes
+P = rows @ V^T tile by tile.
 
-The dynamic minor-dimension gather is interpret-mode-verified on CPU (the
-repo's convention, tests/test_pallas_kernels.py); TPU-native tuning rides
-the existing "TPU-native validation" roadmap item.  Pad slots (coef == 0)
-gather column 0 harmlessly.
+TPU mapping:
+* grid = (b/bt, n/nt); the inner axis streams (bt, nt) tiles of the Gram
+  rows and (k, nt) tiles of V through VMEM, so no block spans a whole row
+  (a (128, 65536) f32 row block alone would be 32 MiB of VMEM).
+* Each step is one (bt, nt) x (nt, k) MXU matmul accumulated into the
+  (bt, k) output block, which stays resident across the n axis.
+* VMEM working set per step: bt*nt + k*nt + bt*k floats (about 0.6 MB at
+  bt=128, nt=512, k=64).
 """
 from __future__ import annotations
 
@@ -29,49 +29,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _gather_body(rows_ref, ids_ref, coef_ref, out_ref):
-    iw = pl.program_id(2)
-
-    @pl.when(iw == 0)
+def _gather_body(rows_ref, v_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    r = rows_ref[...].astype(jnp.float32)       # (bt, n)
-    ci = ids_ref[0]                             # (st,) int32 column ids
-    sub = jnp.take(r, ci, axis=1)               # (bt, st) dynamic gather
-    c = coef_ref[0].astype(jnp.float32)         # (st,)
-    out_ref[:, 0] += sub @ c
+    out_ref[...] += jax.lax.dot_general(
+        rows_ref[...].astype(jnp.float32), v_ref[...],
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("bt", "st", "interpret"))
+def _window_weights(sup_ids: jax.Array, coef: jax.Array,
+                    n: int) -> jax.Array:
+    """(k, n) dense weights: V[j, c] = sum over w with sup_ids[j, w] == c of
+    coef[j, w]."""
+    k, _ = coef.shape
+    return jnp.zeros((k, n), jnp.float32).at[
+        jnp.arange(k)[:, None], sup_ids].add(coef.astype(jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=("bt", "nt", "interpret"))
 def cached_assign_dots_pallas(rows: jax.Array, sup_ids: jax.Array,
                               coef: jax.Array, *, bt: int = 128,
-                              st: int = 128,
+                              nt: int = 512,
                               interpret: bool = False) -> jax.Array:
     """rows: (b, n) f32; sup_ids: (k, W) int32; coef: (k, W) -> P (b, k)."""
+    from jax.experimental.pallas import tpu as pltpu
+
     b, n = rows.shape
-    k, w = coef.shape
+    k, _ = coef.shape
 
     bp = -b % bt
-    wp = -w % st
-    rows_p = jnp.pad(rows, ((0, bp), (0, 0)))
-    ids_p = jnp.pad(sup_ids.astype(jnp.int32), ((0, 0), (0, wp)))
-    coef_p = jnp.pad(coef, ((0, 0), (0, wp)))
+    np_ = -n % nt
+    rows_p = jnp.pad(rows, ((0, bp), (0, np_)))
+    v = jnp.pad(_window_weights(sup_ids.astype(jnp.int32), coef, n),
+                ((0, 0), (0, np_)))
 
-    bb = rows_p.shape[0]
-    ww = ids_p.shape[1]
-    grid = (k, bb // bt, ww // st)
+    bb, nn = rows_p.shape
+    grid = (bb // bt, nn // nt)
 
     out = pl.pallas_call(
         _gather_body,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bt, n), lambda j, ib, iw: (ib, 0)),
-            pl.BlockSpec((1, st), lambda j, ib, iw: (j, iw)),
-            pl.BlockSpec((1, st), lambda j, ib, iw: (j, iw)),
+            pl.BlockSpec((bt, nt), lambda ib, jn: (ib, jn)),
+            pl.BlockSpec((k, nt), lambda ib, jn: (0, jn)),
         ],
-        out_specs=pl.BlockSpec((bt, 1), lambda j, ib, iw: (ib, j)),
+        out_specs=pl.BlockSpec((bt, k), lambda ib, jn: (ib, 0)),
         out_shape=jax.ShapeDtypeStruct((bb, k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(rows_p, ids_p, coef_p)
+    )(rows_p, v)
     return out[:b]

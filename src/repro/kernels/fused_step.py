@@ -31,7 +31,11 @@ Two implementations, dispatched by :mod:`repro.kernels.ops`:
   >= 2-center slab (1-center slabs change XLA's gemm lowering), the
   result is BIT-IDENTICAL to the composed step at f32 — the equivalence
   the grid sweep in tests/test_api_grid.py pins — while never holding
-  more than one (b, kc*W) slab live.
+  more than one (b, kc*W) slab live.  The precondition is that XLA's
+  gemm rounds a slab's columns as it does inside the full strip; on the
+  CPU backend its column tiling gives that for window widths that are
+  multiples of 64, and narrower unaligned slabs may differ in the last
+  ulp or two.
 
 Tile defaults and the per-backend tuning story live in docs/perf.md.
 """
@@ -83,56 +87,6 @@ def _precision_cast(kernel: KernelFn, precision: str):
     raise ValueError(f"precision={precision!r} (expected 'f32' or 'bf16')")
 
 
-_HAS_BARRIER = None     # tri-state: unprobed / usable / unusable
-
-
-def _register_barrier_batching() -> bool:
-    """jax 0.4.x ships no vmap batching rule for ``optimization_barrier``
-    — but the multi-restart engine vmaps the whole step, so the slab
-    loop's barriers would make ``restarts>1`` untraceable.  The barrier
-    is elementwise identity, so its batching rule is the trivial
-    passthrough; register it (idempotently) and fall back to no barriers
-    at all if the private registry moves in a future jax.  Called
-    LAZILY from the first fused-step trace, never at import — importing
-    repro.kernels must not mutate jax process globals for programs that
-    never run the fused step."""
-    try:
-        from jax._src.interpreters import batching
-        from jax._src.lax import lax as lax_internal
-
-        prim = lax_internal.optimization_barrier_p
-        if prim not in batching.primitive_batchers:
-            batching.primitive_batchers[prim] = \
-                lambda args, dims: (prim.bind(*args), dims)
-        return True
-    except Exception:                                   # pragma: no cover
-        import warnings
-
-        warnings.warn(
-            "repro.kernels.fused_step: could not make "
-            "lax.optimization_barrier vmap-safe on this jax; the fused "
-            "step stays numerically exact but loses its slab-scheduling "
-            "hint (peak memory may match the composed step)",
-            RuntimeWarning, stacklevel=3)
-        return False
-
-
-def _soft_barrier(args):
-    """``lax.optimization_barrier``: sequences the slab loop so XLA's
-    scheduler cannot hoist every slab's gemm ahead of the running-min
-    chain (which would re-materialize the full strip and erase the
-    streaming memory win).  Identity on VALUES — bit-identity with the
-    composed path is untouched; on a jax whose barrier cannot be made
-    vmap-safe it degrades to a plain identity (scheduling hint lost,
-    numerics unchanged, one-time warning)."""
-    global _HAS_BARRIER
-    if _HAS_BARRIER is None:
-        _HAS_BARRIER = _register_barrier_batching()
-    if not _HAS_BARRIER:                                # pragma: no cover
-        return args
-    return jax.lax.optimization_barrier(args)
-
-
 def _chunk_dists(kernel, cast, xb, sup, coef, sqnorm, diag_b, j0, kk):
     """The composed path's distance block for centers [j0, j0+kk): the
     exact op sequence of ``minibatch._batch_center_dots`` + the distance
@@ -172,7 +126,7 @@ def streaming_assign_xla(kernel: KernelFn, xb: jax.Array, sup: jax.Array,
             upd = cmin < best                  # strict: first-min ties,
             best = jnp.where(upd, cmin, best)  # same as jnp.argmin's
             bidx = jnp.where(upd, cidx, bidx)
-        best, bidx, xb = _soft_barrier((best, bidx, xb))
+        best, bidx, xb = jax.lax.optimization_barrier((best, bidx, xb))
     return best, bidx
 
 
@@ -189,7 +143,7 @@ def streaming_min_xla(kernel: KernelFn, xb: jax.Array, sup: jax.Array,
                           j0, kk)
         cmin = jnp.min(dd, axis=1)
         best = cmin if best is None else jnp.minimum(best, cmin)
-        best, xb = _soft_barrier((best, xb))
+        best, xb = jax.lax.optimization_barrier((best, xb))
     return best
 
 
@@ -208,7 +162,7 @@ def streaming_dists_xla(kernel: KernelFn, xb: jax.Array, sup: jax.Array,
     for j0, kk in center_chunks(k, kc):
         dd = _chunk_dists(kernel, cast, xb, sup, coef, sqnorm, diag_b,
                           j0, kk)
-        dd, xb = _soft_barrier((dd, xb))
+        dd, xb = jax.lax.optimization_barrier((dd, xb))
         out.append(dd)
     return jnp.concatenate(out, axis=1)
 
@@ -238,7 +192,7 @@ def streamed_sqnorm(kernel: KernelFn, x: jax.Array, idx: jax.Array,
     outs = []
     for j0, kk in center_chunks(k, kc):
         o = jax.vmap(one)(idx[j0:j0 + kk], coef[j0:j0 + kk])
-        o, x = _soft_barrier((o, x))
+        o, x = jax.lax.optimization_barrier((o, x))
         outs.append(o)
     return jnp.concatenate(outs)
 
@@ -260,14 +214,14 @@ def streamed_sqnorm_pts(kernel: KernelFn, pts: jax.Array, coef: jax.Array,
     outs = []
     for j0, kk in center_chunks(k, kc):
         o = jax.vmap(one)(pts[j0:j0 + kk], coef[j0:j0 + kk])
-        o, pts = _soft_barrier((o, pts))
+        o, pts = jax.lax.optimization_barrier((o, pts))
         outs.append(o)
     return jnp.concatenate(outs)
 
 
 # ---------------------------------------------------------------- Pallas
-def _stream_body(x_ref, xsq_ref, diag_ref, sup_ref, supsq_ref, coef_ref,
-                 sqn_ref, best_ref, idx_ref, p_acc, *, kind, p0, p1, p2,
+def _stream_body(sqn_ref, x_ref, xsq_ref, diag_ref, sup_ref, supsq_ref,
+                 coef_ref, best_ref, idx_ref, p_acc, *, kind, p0, p1, p2,
                  bf16):
     j = pl.program_id(1)
     iw = pl.program_id(2)
@@ -278,27 +232,25 @@ def _stream_body(x_ref, xsq_ref, diag_ref, sup_ref, supsq_ref, coef_ref,
         p_acc[...] = jnp.zeros_like(p_acc)
 
     x = x_ref[...]
-    s = sup_ref[0]
+    s = sup_ref[...]
     if bf16:
         x = x.astype(jnp.bfloat16)
         s = s.astype(jnp.bfloat16)
     xy = jax.lax.dot_general(x, s, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    kv = _apply_kernel(xy, xsq_ref[...].astype(jnp.float32),
-                       supsq_ref[0].astype(jnp.float32), kind, p0, p1, p2)
-    p_acc[:, 0] += kv @ coef_ref[0].astype(jnp.float32)
+    kv = _apply_kernel(xy, xsq_ref[...], supsq_ref[...], kind, p0, p1, p2)
+    p_acc[...] += jnp.sum(kv * coef_ref[...], axis=1, keepdims=True)
 
     @pl.when(iw == nw - 1)
     def _fold():
-        d = diag_ref[...].astype(jnp.float32) - 2.0 * p_acc[:, 0] \
-            + sqn_ref[0]
+        d = diag_ref[...] - 2.0 * p_acc[...] + sqn_ref[j]
         first = j == 0
-        prev = jnp.where(first, jnp.full_like(d, jnp.inf), best_ref[:, 0])
-        prev_i = jnp.where(first, jnp.zeros_like(idx_ref[:, 0]),
-                           idx_ref[:, 0])
+        prev = jnp.where(first, jnp.full_like(d, jnp.inf), best_ref[...])
+        prev_i = jnp.where(first, jnp.zeros_like(idx_ref[...]),
+                           idx_ref[...])
         upd = d < prev
-        best_ref[:, 0] = jnp.where(upd, d, prev)
-        idx_ref[:, 0] = jnp.where(upd, jnp.full_like(prev_i, j), prev_i)
+        best_ref[...] = jnp.where(upd, d, prev)
+        idx_ref[...] = jnp.where(upd, jnp.full_like(prev_i, j), prev_i)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -313,6 +265,9 @@ def streaming_assign_pallas(
 
     b / W / d are padded to tile multiples (zero support points with zero
     coefficients contribute nothing; padded batch rows are sliced off).
+    Mosaic layout: per-center rows (support norms, coefficients) are
+    (k, 1, W) views read in squeezed (1, st) blocks, per-row vectors are
+    (b, 1) columns, and ``sqnorm`` sits whole in SMEM, read as scalars.
     The online-argmin outputs live in (bt, 1) blocks revisited across the
     two innermost grid axes — never written back per center."""
     from jax.experimental.pallas import tpu as pltpu
@@ -322,37 +277,38 @@ def streaming_assign_pallas(
     bp, wp, dp = -b % bt, -w % st, -d % 128
     xb_p = jnp.pad(xb, ((0, bp), (0, dp)))
     sup_p = jnp.pad(sup, ((0, 0), (0, wp), (0, dp)))
-    coef_p = jnp.pad(coef, ((0, 0), (0, wp)))
-    diag_p = jnp.pad(diag_b, (0, bp))
-    xsq = jnp.sum(xb_p.astype(jnp.float32) ** 2, axis=-1)
-    supsq = jnp.sum(sup_p.astype(jnp.float32) ** 2, axis=-1)
+    coef_p = jnp.pad(coef.astype(jnp.float32), ((0, 0), (0, wp)))[:, None]
+    diag_p = jnp.pad(diag_b.astype(jnp.float32), (0, bp))[:, None]
+    xsq = jnp.sum(xb_p.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
+    supsq = jnp.sum(sup_p.astype(jnp.float32) ** 2, axis=-1)[:, None]
 
     bb, dd = xb_p.shape
     ww = sup_p.shape[1]
     grid = (bb // bt, k, ww // st)
+    col = pl.BlockSpec((bt, 1), lambda ib, j, iw: (ib, 0))
+    row = pl.BlockSpec((None, 1, st), lambda ib, j, iw: (j, 0, iw))
 
     best, idx = pl.pallas_call(
         functools.partial(_stream_body, kind=kind, p0=p0, p1=p1, p2=p2,
                           bf16=bf16),
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bt, dd), lambda ib, j, iw: (ib, 0)),
-            pl.BlockSpec((bt,), lambda ib, j, iw: (ib,)),
-            pl.BlockSpec((bt,), lambda ib, j, iw: (ib,)),
-            pl.BlockSpec((1, st, dd), lambda ib, j, iw: (j, iw, 0)),
-            pl.BlockSpec((1, st), lambda ib, j, iw: (j, iw)),
-            pl.BlockSpec((1, st), lambda ib, j, iw: (j, iw)),
-            pl.BlockSpec((1,), lambda ib, j, iw: (j,)),
+            col,
+            col,
+            pl.BlockSpec((None, st, dd), lambda ib, j, iw: (j, iw, 0)),
+            row,
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((bt, 1), lambda ib, j, iw: (ib, 0)),
-            pl.BlockSpec((bt, 1), lambda ib, j, iw: (ib, 0)),
-        ],
+        out_specs=[col, col],
         out_shape=[
             jax.ShapeDtypeStruct((bb, 1), jnp.float32),
             jax.ShapeDtypeStruct((bb, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(xb_p, xsq, diag_p, sup_p, supsq, coef_p, sqnorm)
+    )(sqnorm.astype(jnp.float32), xb_p, xsq, diag_p, sup_p, supsq, coef_p)
     return best[:b, 0], idx[:b, 0]

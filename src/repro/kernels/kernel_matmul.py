@@ -11,6 +11,9 @@ tiles (FlashAttention-style) and contracts immediately:
 
 Arithmetic intensity rises from ~1 flop/byte (kernel matrix read) to
 ~min(nt, mt) flop/byte — firmly compute-bound on the MXU for 128x128 tiles.
+Squared norms enter as an (n, 1) column and a (1, m) row, so their blocks
+keep Mosaic's 2-D (8, 128) tiling; 1-D blocks get an XLA layout that
+Mosaic rejects.
 """
 from __future__ import annotations
 
@@ -35,8 +38,7 @@ def _km_body(x_ref, xsq_ref, y_ref, ysq_ref, v_ref, out_ref,
     y = y_ref[...].astype(jnp.float32)          # (mt, d)
     xy = jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (nt, mt)
-    kv = _apply_kernel(xy, xsq_ref[...].astype(jnp.float32),
-                       ysq_ref[...].astype(jnp.float32), kind, p0, p1, p2)
+    kv = _apply_kernel(xy, xsq_ref[...], ysq_ref[...], kind, p0, p1, p2)
     out_ref[...] += kv @ v_ref[...].astype(jnp.float32)
 
 
@@ -52,6 +54,8 @@ def kernel_matmul_pallas(x: jax.Array, y: jax.Array, v: jax.Array, *,
     Padding: m-padding rows get v = 0 (no contribution for any kernel);
     n-padding rows are sliced off; d zero-padded (distance/dot preserving).
     """
+    from jax.experimental.pallas import tpu as pltpu
+
     n, d = x.shape
     m, c = v.shape
 
@@ -62,8 +66,8 @@ def kernel_matmul_pallas(x: jax.Array, y: jax.Array, v: jax.Array, *,
     x_p = jnp.pad(x, ((0, np_), (0, dp)))
     y_p = jnp.pad(y, ((0, mp), (0, dp)))
     v_p = jnp.pad(v, ((0, mp), (0, cp)))
-    xsq = jnp.sum(x_p.astype(jnp.float32) ** 2, axis=-1)
-    ysq = jnp.sum(y_p.astype(jnp.float32) ** 2, axis=-1)
+    xsq = jnp.sum(x_p.astype(jnp.float32) ** 2, axis=-1)[:, None]  # (n+, 1)
+    ysq = jnp.sum(y_p.astype(jnp.float32) ** 2, axis=-1)[None, :]  # (1, m+)
 
     nn, dd = x_p.shape
     mm = y_p.shape[0]
@@ -75,13 +79,15 @@ def kernel_matmul_pallas(x: jax.Array, y: jax.Array, v: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((nt, dd), lambda i, im: (i, 0)),
-            pl.BlockSpec((nt,), lambda i, im: (i,)),
+            pl.BlockSpec((nt, 1), lambda i, im: (i, 0)),
             pl.BlockSpec((mt, dd), lambda i, im: (im, 0)),
-            pl.BlockSpec((mt,), lambda i, im: (im,)),
+            pl.BlockSpec((1, mt), lambda i, im: (0, im)),
             pl.BlockSpec((mt, cc), lambda i, im: (im, 0)),
         ],
         out_specs=pl.BlockSpec((nt, cc), lambda i, im: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nn, cc), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x_p, xsq, y_p, ysq, v_p)
     return out[:n, :c]

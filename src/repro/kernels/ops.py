@@ -69,7 +69,7 @@ def fused_batch_center_dots(kernel: KernelFn, xb: jax.Array,
 
 
 def cached_assign_dots(rows: jax.Array, sup_ids: jax.Array,
-                       coef: jax.Array, bt: int = 128, st: int = 128,
+                       coef: jax.Array, bt: int = 128, nt: int = 512,
                        interpret=None) -> jax.Array:
     """P[i,j] = sum_w coef[j,w] rows[i, sup_ids[j,w]] — the assignment
     contraction over cache-resolved Gram rows (no kernel evaluations; the
@@ -78,8 +78,8 @@ def cached_assign_dots(rows: jax.Array, sup_ids: jax.Array,
         interpret = _interpret_default()
     if interpret:
         bt = _clamp_tile(bt, rows.shape[0], 8)
-        st = _clamp_tile(st, coef.shape[1], 8)
-    return cached_assign_dots_pallas(rows, sup_ids, coef, bt=bt, st=st,
+        nt = _clamp_tile(nt, rows.shape[1], 8)
+    return cached_assign_dots_pallas(rows, sup_ids, coef, bt=bt, nt=nt,
                                      interpret=interpret)
 
 

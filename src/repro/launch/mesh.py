@@ -4,6 +4,18 @@ jax initialization)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  The clustering plans place their
+    arrays with NamedShardings and vmap or jit whole fits over them,
+    leaving the sharding of every intermediate (gathers by sampled index
+    above all) to the compiler's propagation; on an ``Explicit`` axis
+    (what ``jax.make_mesh`` gives by default) each such gather would
+    instead need an output sharding spelled out by hand."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -298,6 +298,9 @@ def serve_service(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--reduced", action="store_true")

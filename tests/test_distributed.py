@@ -89,9 +89,8 @@ STEP_EQUIVALENCE = """
 
 ONDEVICE_FIT = """
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import MBConfig, Gaussian
-    from repro.core.distributed import (
-        fit_distributed_jit, predict_distributed, dist_to_center_state)
+    from repro.api import KernelKMeans, SolverConfig
+    from repro.core import Gaussian
     from repro.data import blobs
 
     assert len(jax.devices()) == 8
@@ -99,27 +98,26 @@ ONDEVICE_FIT = """
     x, _ = blobs(n=2048, d=16, k=8, seed=0)
     x = jnp.asarray(x)
     kern = Gaussian(kappa=jnp.float32(2.0))
-    cfg = MBConfig(k=8, batch_size=128, tau=64, max_iters=15, epsilon=-1.0)
+    cfg = SolverConfig(k=8, batch_size=128, tau=64, max_iters=15,
+                       epsilon=-1.0, kernel=kern, cache="none",
+                       distribution="sharded", jit=True)
     init_idx = jnp.arange(8, dtype=jnp.int32) * 100
 
     # whole early-stopped loop on-device: dataset sharded, batches sampled
     # shard-locally, zero per-step host sync
-    dst, iters = fit_distributed_jit(x, x[init_idx], kern, cfg, mesh,
-                                     jax.random.PRNGKey(3))
-    assert int(iters) == cfg.max_iters
+    est = KernelKMeans(cfg, mesh=mesh).fit(x, 3, init_idx=init_idx)
+    dst = est.state_
+    assert int(est.iters_) == cfg.max_iters
     assert bool(jnp.all(jnp.isfinite(dst.sqnorm)))
     assert float(jnp.sum(dst.counts)) == cfg.batch_size * cfg.max_iters
 
     # early stopping still terminates the on-device loop
-    dst2, iters2 = fit_distributed_jit(
-        x, x[init_idx], kern, cfg._replace(max_iters=300, epsilon=0.01),
-        mesh, jax.random.PRNGKey(4))
-    assert int(iters2) < 300
+    est2 = KernelKMeans(cfg.replace(max_iters=300, epsilon=0.01),
+                        mesh=mesh).fit(x, 4, init_idx=init_idx)
+    assert int(est2.iters_) < 300
 
     # sharded serving straight from the distributed state
-    cs = dist_to_center_state(dst)
-    sup = dst.pts.reshape(-1, dst.pts.shape[-1])
-    pred = predict_distributed(cs, sup, x[:999], kern, mesh)
+    pred = est.predict(x[:999])
     assert pred.shape == (999,)
     assert int(jnp.max(pred)) < 8 and int(jnp.min(pred)) >= 0
     print("ONDEVICE-OK")
@@ -129,8 +127,8 @@ ONDEVICE_FIT = """
 ENGINE_8DEV = """
     import time
     import jax, jax.numpy as jnp, numpy as np
-    from repro.core import MBConfig, Gaussian, fit_jit
-    from repro.core.engine import MultiRestartEngine
+    from repro.api import KernelKMeans, SolverConfig
+    from repro.core import Gaussian
     from repro.data import blobs
     from repro.launch.mesh import make_restart_mesh
 
@@ -138,15 +136,16 @@ ENGINE_8DEV = """
     x, _ = blobs(n=2048, d=16, k=8, seed=0)
     x = jnp.asarray(x)
     kern = Gaussian(kappa=jnp.float32(2.0))
-    cfg = MBConfig(k=8, batch_size=128, tau=64, max_iters=15, epsilon=-1.0)
+    cfg = SolverConfig(k=8, batch_size=128, tau=64, max_iters=15,
+                       epsilon=-1.0, kernel=kern, cache="none",
+                       distribution="single", jit=True)
 
     # restart-sharded engine == unsharded engine, bitwise-comparable
     mesh = make_restart_mesh(4)
     assert mesh.devices.size == 4
-    eng = MultiRestartEngine(kern, cfg, restarts=4, mesh=mesh)
-    res = eng.fit(x, jax.random.PRNGKey(0))
-    eng0 = MultiRestartEngine(kern, cfg, restarts=4)
-    res0 = eng0.fit(x, jax.random.PRNGKey(0))
+    eng = KernelKMeans(cfg.replace(restarts=4), mesh=mesh).fit(x, 0)
+    eng0 = KernelKMeans(cfg.replace(restarts=4)).fit(x, 0)
+    res, res0 = eng.result_, eng0.result_
     np.testing.assert_allclose(np.asarray(res.objectives),
                                np.asarray(res0.objectives), atol=1e-6)
     assert int(res.best) == int(res0.best)
@@ -157,16 +156,16 @@ ENGINE_8DEV = """
     np.testing.assert_array_equal(np.asarray(p), np.asarray(p0))
 
     # wall-clock: best-of-4 in one compiled program stays under 2x the
-    # repo's single-restart entry point (fit_jit pays a re-trace per call;
-    # the engine amortizes its compile across fits)
+    # single-restart compiled fit's first call (a new single-restart
+    # program pays its trace and compile; the engine amortizes its
+    # compile across fits)
     init_idx = jnp.arange(8, dtype=jnp.int32) * 100
     t0 = time.perf_counter()
-    _, it = fit_jit(x, kern, cfg, jax.random.PRNGKey(5), init_idx)
-    jax.block_until_ready(it)
+    jax.block_until_ready(
+        KernelKMeans(cfg).fit(x, 5, init_idx=init_idx).state_)
     t_single = time.perf_counter() - t0
     t0 = time.perf_counter()
-    r = eng.fit(x, jax.random.PRNGKey(5))
-    jax.block_until_ready(r.objectives)
+    jax.block_until_ready(eng.fit(x, 5).result_.objectives)
     t_multi = time.perf_counter() - t0
     ratio = t_multi / t_single
     print(f"R4 vs single ratio: {ratio:.2f}")

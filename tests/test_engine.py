@@ -2,7 +2,7 @@
 
 Everything here runs in the main pytest process on the single real CPU
 device (a 1-device mesh exercises the full shard_map machinery — specs,
-collectives over size-1 axes, compat shim); the 8-virtual-device variants
+collectives over size-1 axes); the 8-virtual-device variants
 live in test_distributed.py subprocesses.  No hypothesis dependency: these
 parametrized sweeps are the always-on fast lane of the invariant coverage.
 """
@@ -38,7 +38,7 @@ def _mesh1():
 
 # ------------------------------------------------- shard_map on one device
 def test_single_device_shardmap_step_matches_make_step():
-    """compat.shard_map on a (1,1) mesh == the plain single-device step,
+    """jax.shard_map on a (1,1) mesh == the plain single-device step,
     trajectory-for-trajectory."""
     x = _blobs()
     cfg = MBConfig(k=8, batch_size=128, tau=64, max_iters=8, epsilon=-1.0)
