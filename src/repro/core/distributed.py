@@ -786,9 +786,6 @@ def predict_distributed(state: CenterState, x: jax.Array, xq: jax.Array,
     counts by padding.  The compiled program is cached per
     (mesh, axes, kernel structure, chunk) so repeated serving calls don't
     re-trace."""
-    from repro.launch.mesh import auto_axes
-
-    mesh = auto_axes(mesh)
     if data_axes is None:
         data_axes = tuple(a for a in mesh.axis_names if a != "model")
     data_axes = tuple(data_axes)

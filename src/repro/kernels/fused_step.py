@@ -32,10 +32,11 @@ Two implementations, dispatched by :mod:`repro.kernels.ops`:
   result is BIT-IDENTICAL to the composed step at f32 — the equivalence
   the grid sweep in tests/test_api_grid.py pins — while never holding
   more than one (b, kc*W) slab live.  The precondition is that XLA's
-  gemm rounds a slab's columns as it does inside the full strip; on the
-  CPU backend its column tiling gives that for window widths that are
-  multiples of 64, and narrower unaligned slabs may differ in the last
-  ulp or two.
+  gemm rounds a slab's columns as it does inside the full strip.  On the
+  CPU backend that holds when the gemms' column counts are multiples of
+  64; at other window widths a column's rounding depends on the gemm's
+  total width, and the slab distances differ from the strip's by at most
+  2 ulp (tests/test_fused_step.py pins both cases).
 
 Tile defaults and the per-backend tuning story live in docs/perf.md.
 """

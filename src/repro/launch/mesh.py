@@ -7,15 +7,21 @@ import jax
 from jax.sharding import AxisType, Mesh
 
 
+def _auto(n: int) -> tuple:
+    return (AxisType.Auto,) * n
+
+
 def auto_axes(mesh: Mesh) -> Mesh:
     """``mesh`` with every axis ``Auto``.  The clustering plans place their
     arrays with NamedShardings and vmap or jit whole fits over them,
     leaving the sharding of every intermediate (gathers by sampled index
     above all) to the compiler's propagation; on an ``Explicit`` axis
     (what ``jax.make_mesh`` gives by default) each such gather would
-    instead need an output sharding spelled out by hand."""
-    return Mesh(mesh.devices, mesh.axis_names,
-                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    instead need an output sharding spelled out by hand.  The clustering
+    mesh makers below build Auto meshes; ``repro.api`` passes a mesh a
+    caller built some other way through this once."""
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=_auto(
+        len(mesh.axis_names)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,7 +44,8 @@ def make_cluster_mesh(model: int = 1):
     ``repro.api`` when ``distribution='sharded'`` is asked for without an
     explicit mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((max(n // model, 1), model), ("data", "model"))
+    return jax.make_mesh((max(n // model, 1), model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_restart_mesh(restarts: int, axis: str = "restart"):
@@ -52,7 +59,8 @@ def make_restart_mesh(restarts: int, axis: str = "restart"):
     devs = jax.devices()
     size = next(d for d in range(min(restarts, len(devs)), 0, -1)
                 if restarts % d == 0)
-    return jax.make_mesh((size,), (axis,), devices=devs[:size])
+    return jax.make_mesh((size,), (axis,), devices=devs[:size],
+                         axis_types=_auto(1))
 
 
 def make_fused_mesh(restarts: int, model: int = 1,
@@ -76,7 +84,8 @@ def make_fused_mesh(restarts: int, model: int = 1,
             f"divides {rem}, or shrink the restart count)")
     data = max(rem // model, 1)
     return jax.make_mesh((r, data, model), axes,
-                         devices=devs[:r * data * model])
+                         devices=devs[:r * data * model],
+                         axis_types=_auto(len(axes)))
 
 
 def data_axes_of(mesh) -> tuple:

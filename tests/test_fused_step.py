@@ -75,18 +75,36 @@ def test_center_chunks_cover_and_never_width_one():
 
 
 # ----------------------------------------------- streaming XLA fallback
+def _assert_ulp(got, want, maxulp, msg=""):
+    got, want = np.asarray(got), np.asarray(want)
+    if maxulp:
+        np.testing.assert_array_max_ulp(got, want, maxulp=maxulp)
+    else:
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32), err_msg=msg)
+
+
 @pytest.mark.parametrize("kname", ["gaussian", "linear", "polynomial"])
-@pytest.mark.parametrize("b,k,w,d", [
-    (32, 4, 64, 8), (37, 7, 64, 9), (64, 13, 40, 3),
+@pytest.mark.parametrize("b,k,w,d,maxulp", [
+    (32, 4, 48, 8, 2), (37, 7, 21, 9, 2), (64, 13, 40, 3, 0),
+    (32, 4, 64, 8, 0), (37, 7, 64, 9, 0),
 ])
-def test_streaming_xla_bit_identical_to_composed(kname, b, k, w, d):
+def test_streaming_xla_bit_identical_to_composed(kname, b, k, w, d, maxulp):
     """The fallback's running argmin/min over >=2-center slabs reproduces
     the composed full-matrix pass BIT-exactly (the property the plan-grid
-    equivalence in test_api_grid.py rests on).  Precondition: XLA's CPU
-    gemm must round a slab's columns as it rounds them inside the full
-    strip.  Its column tiling makes that hold for window widths that are
-    multiples of 64 (and for any width at tiny d), so the unaligned axes
-    here are b, k (k=7 merges a width-1 remainder) and d."""
+    equivalence in test_api_grid.py rests on) whenever XLA rounds a slab's
+    gemm columns as it rounds them inside the full strip.
+
+    On XLA:CPU (jax 0.9.0) that holds when every gemm's column count is a
+    multiple of 64 (W=64 here) or d is tiny (d=3); otherwise the rounding
+    of a column depends on the gemm's total column count, not only on the
+    column's position (aligning the slabs to 64-column blocks of the strip
+    does not restore it).  At the unaligned widths W=48 and W=21 the slab
+    cross entries then differ from the strip's in the last bits, and the
+    distances by at most 2 ulp (measured there: 1 ulp gaussian, 2 linear,
+    2 polynomial).  Those cases hold the multi-slab passes to ``maxulp``
+    and the labels to equality; a single slab (kc >= k) is the composed
+    pass itself and stays bit-exact everywhere."""
     from repro.core.kernel_fns import kernel_cross
 
     kern, _ = KERNELS[kname]
@@ -108,27 +126,22 @@ def test_streaming_xla_bit_identical_to_composed(kname, b, k, w, d):
 
     want_min, want_idx = composed(xb, sup, coef, sq, diag_b)
     for kc in (2, 4, k):
+        ulp = 0 if kc >= k else maxulp
         assign = jax.jit(lambda *a, kc=kc:
                          fs.streaming_assign_xla(kern, *a, kc=kc))
         got_min, got_idx = assign(xb, sup, coef, sq, diag_b)
-        np.testing.assert_array_equal(
-            np.asarray(got_min).view(np.uint32),
-            np.asarray(want_min).view(np.uint32), err_msg=f"kc={kc}")
+        _assert_ulp(got_min, want_min, ulp, msg=f"kc={kc}")
         np.testing.assert_array_equal(np.asarray(got_idx),
                                       np.asarray(want_idx))
         only_min = jax.jit(lambda *a, kc=kc:
                            fs.streaming_min_xla(kern, *a, kc=kc))(
             xb, sup, coef, sq, diag_b)
-        np.testing.assert_array_equal(
-            np.asarray(only_min).view(np.uint32),
-            np.asarray(want_min).view(np.uint32))
+        _assert_ulp(only_min, want_min, ulp)
         dists = jax.jit(lambda *a, kc=kc:
                         fs.streaming_dists_xla(kern, *a, kc=kc))(
             xb, sup, coef, sq, diag_b)
         assert dists.shape == (b, k)
-        np.testing.assert_array_equal(
-            np.asarray(jnp.min(dists, axis=1)).view(np.uint32),
-            np.asarray(want_min).view(np.uint32))
+        _assert_ulp(jnp.min(dists, axis=1), want_min, ulp)
 
 
 def test_streamed_sqnorm_bit_identical_to_recompute():
