@@ -35,6 +35,7 @@ from repro.api.executors import (
     _assign, _distances, carry_of, outcome_from_carry, FitCarry,
 )
 from repro.core.kernel_fns import kernel_spec, make_kernel
+from repro.core.loop import span
 from repro.core.state import CenterState
 
 # SolverConfig fields that are JSON-serializable as-is (everything except
@@ -134,12 +135,13 @@ class KernelKMeans:
         estimator derives init/fit keys through :mod:`repro.api.keys`, so
         the same seed draws the same batch sequence on every
         single-restart plan."""
-        X = jnp.asarray(X)
-        key = api_keys.as_key(key)
-        plan = self.plan_for(X.shape[0])
-        out = plan.executor.fit(X, key, init_idx=init_idx,
-                                sample_weight=sample_weight)
-        self._set_fitted(X, out)
+        with span("kkm.fit"):
+            X = jnp.asarray(X)
+            key = api_keys.as_key(key)
+            plan = self.plan_for(X.shape[0])
+            out = plan.executor.fit(X, key, init_idx=init_idx,
+                                    sample_weight=sample_weight)
+            self._set_fitted(X, out)
         return self
 
     def partial_fit(self, X, key: Any = 0, *, iters: Optional[int] = None):

@@ -45,7 +45,7 @@ from repro.core.loop import (  # noqa: F401  (canonical home: the loop core;
     clear_program_cache, lookup_program, outcome_from_carry, program_builds,
 )
 from repro.core.loop import loop_config as _loop_mb
-from repro.core.loop import precision_plan
+from repro.core.loop import precision_plan, span
 from repro.core.minibatch import (
     assign_chunked, center_distances_chunked, host_fit_loop, make_step,
     run_early_stopped, run_early_stopped_keyed, sampled_step_with_key,
@@ -229,8 +229,9 @@ class SingleExecutor(Executor):
                                           cfg.init)
 
         if self._use_jit(sample_weight):
-            run = self._jit_run("init", mb.max_iters)
-            state, iters, out_key = run(x, init_idx, fit_key)
+            with span("kkm.run"):
+                run = self._jit_run("init", mb.max_iters)
+                state, iters, out_key = run(x, init_idx, fit_key)
             return FitOutcome(state=state, iters=iters, key=out_key,
                               steps=None)
 

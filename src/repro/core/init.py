@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kernel_fns import KernelFn, kernel_cross, kernel_diag
+from repro.core.loop import scope, span
 
 
 def kmeans_plus_plus(key: jax.Array, x: jax.Array, k: int,
@@ -73,9 +74,10 @@ def draw_init(key: jax.Array, x: jax.Array, k: int, kernel: KernelFn,
     """The one init-drawing entry every fit path shares (it used to be
     copy-pasted across ``fit`` / ``fit_cached`` / the engine): dispatch on
     the method name, return (k,) int32 indices into ``x``."""
-    if method == "kmeans++":
-        return kmeans_plus_plus(key, x, k, kernel)
-    if method == "random":
-        return random_init(key, x.shape[0], k)
+    with span("kkm.init"), scope("kkm.init"):
+        if method == "kmeans++":
+            return kmeans_plus_plus(key, x, k, kernel)
+        if method == "random":
+            return random_init(key, x.shape[0], k)
     raise ValueError(f"unknown init method {method!r} "
                      "(expected 'kmeans++' or 'random')")

@@ -30,8 +30,10 @@ module owns the loop skeleton exactly once:
   - :func:`lookup_program` — donation-aware compiled-program caching
     (the ``program_builds()`` counter lives here).
 
-* **Carry/telemetry** — :class:`FitOutcome` (what a fit produced) and
-  :class:`FitCarry` (the resumable part ``partial_fit`` / ``save`` need).
+* **Carry/telemetry** — :class:`FitOutcome` (what a fit produced),
+  :class:`FitCarry` (the resumable part ``partial_fit`` / ``save`` need),
+  and the fit path's trace names (:data:`STAGES`, :func:`span`,
+  :func:`scope`).
 
 * **Lowering description** — :class:`LoopSpec` + :func:`stages`: every
   executor family describes itself as a declarative lowering (sampler,
@@ -240,6 +242,37 @@ def program_builds() -> int:
     import — a monotone counter; snapshot it around a fit to assert the
     fit re-bound nothing."""
     return _PROGRAM_BUILDS[0]
+
+
+# The fit path's names in a profiler trace, all of them here.  Host spans
+# (``span``): ``kkm.fit`` around ``KernelKMeans.fit``, ``kkm.init`` around
+# the init draw, ``kkm.run`` around the compiled program's lookup and
+# dispatch.  Device stages (``scope``, trace-time metadata only): the
+# on-device loop, the batch draw, and the Algorithm-2 step's gathers,
+# assignment pass, rates and ring append, <C_j, C_j> recompute, objective
+# pass, and the streaming kernel's padding; ``kkm.init`` also names the
+# init's device ops.  A device op's stage is the innermost ``kkm.*``
+# component of its scope path.
+STAGES = ("kkm.fit", "kkm.init", "kkm.run", "kkm.loop", "kkm.sample",
+          "kkm.gather", "kkm.assign", "kkm.update", "kkm.sqnorm",
+          "kkm.objective", "kkm.pad")
+
+
+def span(name: str):
+    """A host span on the profiler's clock (``TraceAnnotation``); under a
+    microsecond per span when no trace is being taken."""
+    if name not in STAGES:
+        raise ValueError(f"unknown fit-path span {name!r}")
+    return jax.profiler.TraceAnnotation(name)
+
+
+def scope(name: str):
+    """A device stage: names every op traced inside it
+    (``jax.named_scope``); changes no value and costs nothing at run
+    time."""
+    if name not in STAGES:
+        raise ValueError(f"unknown fit-path stage {name!r}")
+    return jax.named_scope(name)
 
 
 def clear_program_cache() -> None:
@@ -469,13 +502,15 @@ def run_early_stopped_keyed(cfg, step_with_key, state, key: jax.Array):
 
     def body(carry):
         state, key, i, _ = carry
-        key, kb = api_keys.next_batch_key(key)
+        with scope("kkm.sample"):
+            key, kb = api_keys.next_batch_key(key)
         state, imp = step_with_key(state, kb)
         return state, key, i + 1, imp
 
     init_carry = (state, key, jnp.zeros((), jnp.int32),
                   jnp.full((), jnp.inf, jnp.float32))
-    state, key, iters, _ = jax.lax.while_loop(cond, body, init_carry)
+    with scope("kkm.loop"):
+        state, key, iters, _ = jax.lax.while_loop(cond, body, init_carry)
     return state, iters, key
 
 

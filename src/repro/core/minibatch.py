@@ -30,7 +30,7 @@ from repro.core.kernel_fns import (
 )
 from repro.core.loop import (  # noqa: F401  (re-exported loop-core names)
     compress_hook, drive_fit_loop, precision_plan, run_early_stopped,
-    run_early_stopped_keyed,
+    run_early_stopped_keyed, scope,
 )
 from repro.core.rates import get_rate
 from repro.core.state import CenterState, init_state, window_size
@@ -194,33 +194,39 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
 
     def step(state: CenterState, x: jax.Array, batch_idx: jax.Array):
         k, w = state.idx.shape
-        xb = x[batch_idx]                                          # (b, d)
-        diag_b = diag_of(kernel, xb)                              # (b,)
+        with scope("kkm.gather"):
+            xb = x[batch_idx]                                      # (b, d)
+            diag_b = diag_of(kernel, xb)                          # (b,)
+            sup = None if index_data else x[state.idx.reshape(-1)]
 
         # ---- (2) streaming assignment: online argmin over centers ---------
-        if index_data:
-            # cached/precomputed: ONE bulk row resolve (the composed
-            # dots), then min/argmin — per-slab lookups would re-run the
-            # cache's key scan k/kc times for values that are gathers
-            p = _batch_center_dots(kernel, xb, x, state.idx, state.coef,
-                                   cfg.use_pallas)
-            dists = diag_b[:, None] - 2.0 * p + state.sqnorm[None, :]
-            best = jnp.min(dists, axis=1)
-            assign = jnp.argmin(dists, axis=1).astype(jnp.int32)
-        else:
-            best, assign = kops.streaming_assign(
-                kernel, xb, x[state.idx.reshape(-1)], state.coef,
-                state.sqnorm, diag_b, precision=precision)
-        f_before = jnp.mean(best)
-        onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)      # (b, k)
-        bj = jnp.sum(onehot, axis=0)                               # (k,)
+        with scope("kkm.assign"):
+            if index_data:
+                # cached/precomputed: ONE bulk row resolve (the composed
+                # dots), then min/argmin — per-slab lookups would re-run
+                # the cache's key scan k/kc times for values that are
+                # gathers
+                p = _batch_center_dots(kernel, xb, x, state.idx,
+                                       state.coef, cfg.use_pallas)
+                dists = diag_b[:, None] - 2.0 * p + state.sqnorm[None, :]
+                best = jnp.min(dists, axis=1)
+                assign = jnp.argmin(dists, axis=1).astype(jnp.int32)
+            else:
+                best, assign = kops.streaming_assign(
+                    kernel, xb, sup, state.coef, state.sqnorm, diag_b,
+                    precision=precision)
 
         # ---- (3)/(4) rates + ring append: shared with the composed step ---
-        alpha = rate_fn(bj, state.counts, b)                       # (k,)
-        coef_scaled = state.coef * (1.0 - alpha)[:, None]
-        new_idx, new_coef, new_head, _, _ = _append_to_windows(
-            state.idx, coef_scaled, state.head, alpha, bj, onehot,
-            batch_idx)
+        with scope("kkm.update"):
+            f_before = jnp.mean(best)
+            onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)  # (b, k)
+            bj = jnp.sum(onehot, axis=0)                           # (k,)
+            alpha = rate_fn(bj, state.counts, b)                   # (k,)
+            coef_scaled = state.coef * (1.0 - alpha)[:, None]
+            new_idx, new_coef, new_head, _, _ = _append_to_windows(
+                state.idx, coef_scaled, state.head, alpha, bj, onehot,
+                batch_idx)
+            counts = state.counts + bj
 
         # ---- (5) center squared norms (paper-faithful recompute) ----------
         # streamed center-chunked recompute: the (k, W, W) Gram stack is
@@ -228,28 +234,32 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
         # step's peak-memory win.  Index-data kernels keep the composed
         # bulk-lookup recompute: one row resolve beats k/kc chunked
         # resolves, and their Gram values are gathers anyway.
-        if index_data:
-            new_sqnorm = _sqnorm_recompute(kernel, x, new_idx, new_coef)
-        else:
-            from repro.kernels.fused_step import streamed_sqnorm
-            new_sqnorm = streamed_sqnorm(kernel, x, new_idx, new_coef,
-                                         compute_dtype=cdt)
+        with scope("kkm.sqnorm"):
+            if index_data:
+                new_sqnorm = _sqnorm_recompute(kernel, x, new_idx, new_coef)
+            else:
+                from repro.kernels.fused_step import streamed_sqnorm
+                new_sqnorm = streamed_sqnorm(kernel, x, new_idx, new_coef,
+                                             compute_dtype=cdt)
 
         # ---- (6) streaming objective on the NEW centers -------------------
-        if index_data:
-            p_new = _batch_center_dots(kernel, xb, x, new_idx, new_coef,
-                                       cfg.use_pallas)
-            d_new = diag_b[:, None] - 2.0 * p_new + new_sqnorm[None, :]
-            best2 = jnp.min(d_new, axis=1)
-        else:
-            best2 = kops.streaming_min(
-                kernel, xb, x[new_idx.reshape(-1)], new_coef, new_sqnorm,
-                diag_b, precision=precision)
-        f_after = jnp.mean(best2)
+        with scope("kkm.gather"):
+            new_sup = None if index_data else x[new_idx.reshape(-1)]
+        with scope("kkm.objective"):
+            if index_data:
+                p_new = _batch_center_dots(kernel, xb, x, new_idx, new_coef,
+                                           cfg.use_pallas)
+                d_new = diag_b[:, None] - 2.0 * p_new + new_sqnorm[None, :]
+                best2 = jnp.min(d_new, axis=1)
+            else:
+                best2 = kops.streaming_min(
+                    kernel, xb, new_sup, new_coef, new_sqnorm, diag_b,
+                    precision=precision)
+            f_after = jnp.mean(best2)
 
         new_state = CenterState(
             idx=new_idx, coef=new_coef, head=new_head, sqnorm=new_sqnorm,
-            counts=state.counts + bj, step=state.step + 1)
+            counts=counts, step=state.step + 1)
         info = StepInfo(f_before=f_before, f_after=f_after,
                         improvement=f_before - f_after,
                         batch_counts=bj, assignments=assign)
@@ -290,83 +300,93 @@ def make_step(kernel: KernelFn, cfg: MBConfig):
 
     def step(state: CenterState, x: jax.Array, batch_idx: jax.Array):
         k, w = state.idx.shape
-        xb = x[batch_idx]                                          # (b, d)
-        diag_b = diag_of(kernel, xb)                              # (b,)
+        with scope("kkm.gather"):
+            xb = x[batch_idx]                                      # (b, d)
+            diag_b = diag_of(kernel, xb)                          # (b,)
 
         # ---- (2) assignment against current truncated centers -------------
-        p = _batch_center_dots(kernel, xb, x, state.idx, state.coef,
-                               cfg.use_pallas, cdt=cdt)            # (b, k)
-        dists = diag_b[:, None] - 2.0 * p + state.sqnorm[None, :]
-        f_before = jnp.mean(jnp.min(dists, axis=1))
-        assign = jnp.argmin(dists, axis=1).astype(jnp.int32)
-        onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)      # (b, k)
-        bj = jnp.sum(onehot, axis=0)                               # (k,)
+        with scope("kkm.assign"):
+            p = _batch_center_dots(kernel, xb, x, state.idx, state.coef,
+                                   cfg.use_pallas, cdt=cdt)        # (b, k)
+            dists = diag_b[:, None] - 2.0 * p + state.sqnorm[None, :]
+            assign = jnp.argmin(dists, axis=1).astype(jnp.int32)
 
-        # ---- (3) learning rate --------------------------------------------
-        alpha = rate_fn(bj, state.counts, b)                       # (k,)
-        decay = 1.0 - alpha
+        with scope("kkm.update"):
+            f_before = jnp.mean(jnp.min(dists, axis=1))
+            onehot = jax.nn.one_hot(assign, k, dtype=jnp.float32)  # (b, k)
+            bj = jnp.sum(onehot, axis=0)                           # (k,)
 
-        # ---- (4) decay + ring append --------------------------------------
-        coef_scaled = state.coef * decay[:, None]
-        new_idx, new_coef, new_head, evict_idx, evict_coef = _append_to_windows(
-            state.idx, coef_scaled, state.head, alpha, bj, onehot, batch_idx)
+            # ---- (3) learning rate ----------------------------------------
+            alpha = rate_fn(bj, state.counts, b)                   # (k,)
+            decay = 1.0 - alpha
+
+            # ---- (4) decay + ring append ----------------------------------
+            coef_scaled = state.coef * decay[:, None]
+            new_idx, new_coef, new_head, evict_idx, evict_coef = \
+                _append_to_windows(state.idx, coef_scaled, state.head,
+                                   alpha, bj, onehot, batch_idx)
+            counts = state.counts + bj
+            onehot_n = onehot / jnp.maximum(bj, 1.0)[None, :]      # (b, k)
 
         # ---- (5) center squared norms --------------------------------------
-        onehot_n = onehot / jnp.maximum(bj, 1.0)[None, :]          # (b, k)
-        if cfg.sqnorm_mode == "recompute":
-            new_sqnorm = _sqnorm_recompute(kernel, x, new_idx, new_coef,
-                                           cdt=cdt)
-            kbb = None
-        elif cfg.sqnorm_mode == "incremental":
-            # <C', C'> for the *untruncated* update, then subtract the
-            # evicted component D:  <C-D, C-D> = <C,C> - 2<C-D, D> - <D,D>.
-            kbb = _f32(kernel_cross(kernel, _c(xb), _c(xb)))       # (b, b)
-            cm_cross = jnp.sum(onehot * p, axis=0) / jnp.maximum(bj, 1.0)
-            cm_sq = jnp.sum(onehot_n * (kbb @ onehot_n), axis=0)   # (k,)
-            sq_untrunc = (decay ** 2 * state.sqnorm
-                          + 2.0 * decay * alpha * cm_cross
-                          + alpha ** 2 * cm_sq)
+        with scope("kkm.sqnorm"):
+            if cfg.sqnorm_mode == "recompute":
+                new_sqnorm = _sqnorm_recompute(kernel, x, new_idx, new_coef,
+                                               cdt=cdt)
+                kbb = None
+            elif cfg.sqnorm_mode == "incremental":
+                # <C', C'> for the *untruncated* update, then subtract the
+                # evicted component D:
+                # <C-D, C-D> = <C,C> - 2<C-D, D> - <D,D>.
+                kbb = _f32(kernel_cross(kernel, _c(xb), _c(xb)))   # (b, b)
+                cm_cross = jnp.sum(onehot * p, axis=0) / \
+                    jnp.maximum(bj, 1.0)
+                cm_sq = jnp.sum(onehot_n * (kbb @ onehot_n), axis=0)
+                sq_untrunc = (decay ** 2 * state.sqnorm
+                              + 2.0 * decay * alpha * cm_cross
+                              + alpha ** 2 * cm_sq)
 
-            def corr(evict_i, evict_c, idx_row, coef_row):
-                kd_w = _f32(kernel_cross(kernel, _c(x[evict_i]),
-                                         _c(x[idx_row])))            # (b, W)
-                c_d_new = evict_c @ (kd_w @ coef_row)     # <D, C_trunc>
-                kdd = _f32(kernel_cross(kernel, _c(x[evict_i]),
-                                        _c(x[evict_i])))
-                dd = evict_c @ (kdd @ evict_c)            # <D, D>
-                return 2.0 * c_d_new + dd
+                def corr(evict_i, evict_c, idx_row, coef_row):
+                    kd_w = _f32(kernel_cross(kernel, _c(x[evict_i]),
+                                             _c(x[idx_row])))        # (b, W)
+                    c_d_new = evict_c @ (kd_w @ coef_row)  # <D, C_trunc>
+                    kdd = _f32(kernel_cross(kernel, _c(x[evict_i]),
+                                            _c(x[evict_i])))
+                    dd = evict_c @ (kdd @ evict_c)         # <D, D>
+                    return 2.0 * c_d_new + dd
 
-            new_sqnorm = sq_untrunc - jax.vmap(corr)(
-                evict_idx, evict_coef, new_idx, new_coef)
-        else:
-            raise ValueError(cfg.sqnorm_mode)
+                new_sqnorm = sq_untrunc - jax.vmap(corr)(
+                    evict_idx, evict_coef, new_idx, new_coef)
+            else:
+                raise ValueError(cfg.sqnorm_mode)
 
         # ---- (6) batch objective on the NEW centers (early stopping) ------
-        if cfg.eval_mode == "direct":
-            p_new = _batch_center_dots(kernel, xb, x, new_idx, new_coef,
-                                       cfg.use_pallas, cdt=cdt)
-        elif cfg.eval_mode == "delta":
-            # <phi(x), C'_j> = decay_j P[x,j] + alpha_j <phi(x), cm(B_j)>
-            #                  - <phi(x), D_j>           — O(k b^2), no kW pass
-            if kbb is None:
-                kbb = _f32(kernel_cross(kernel, _c(xb), _c(xb)))
-            cm_dot = kbb @ onehot_n                                # (b, k)
+        with scope("kkm.objective"):
+            if cfg.eval_mode == "direct":
+                p_new = _batch_center_dots(kernel, xb, x, new_idx, new_coef,
+                                           cfg.use_pallas, cdt=cdt)
+            elif cfg.eval_mode == "delta":
+                # <phi(x), C'_j> = decay_j P[x,j] + alpha_j <phi(x), cm(B_j)>
+                #                  - <phi(x), D_j>     — O(k b^2), no kW pass
+                if kbb is None:
+                    kbb = _f32(kernel_cross(kernel, _c(xb), _c(xb)))
+                cm_dot = kbb @ onehot_n                            # (b, k)
 
-            def drop_dot(evict_i, evict_c):
-                return _f32(kernel_cross(kernel, _c(xb),
-                                         _c(x[evict_i]))) @ evict_c  # (b,)
+                def drop_dot(evict_i, evict_c):
+                    return _f32(kernel_cross(kernel, _c(xb),
+                                             _c(x[evict_i]))) @ evict_c
 
-            d_dot = jax.vmap(drop_dot)(evict_idx, evict_coef).T    # (b, k)
-            p_new = decay[None, :] * p + alpha[None, :] * cm_dot - d_dot
-        else:
-            raise ValueError(cfg.eval_mode)
+                d_dot = jax.vmap(drop_dot)(evict_idx, evict_coef).T
+                p_new = decay[None, :] * p + alpha[None, :] * cm_dot - d_dot
+            else:
+                raise ValueError(cfg.eval_mode)
 
-        d_new = diag_b[:, None] - 2.0 * p_new + new_sqnorm[None, :]
-        f_after = jnp.mean(jnp.min(d_new, axis=1))
+            d_new = diag_b[:, None] - 2.0 * p_new + new_sqnorm[None, :]
+            f_after = jnp.mean(jnp.min(d_new, axis=1))
 
         new_state = CenterState(
             idx=new_idx, coef=new_coef, head=new_head, sqnorm=new_sqnorm,
-            counts=state.counts + bj, step=state.step + 1)
+            counts=counts, step=state.step + 1)
         info = StepInfo(f_before=f_before, f_after=f_after,
                         improvement=f_before - f_after,
                         batch_counts=bj, assignments=assign)
@@ -584,7 +604,9 @@ def sampled_step_with_key(step, x: jax.Array, cfg: MBConfig):
     n = x.shape[0]
 
     def step_with_key(state, kb):
-        state, info = step(state, x, sample_batch(kb, n, cfg.batch_size))
+        with scope("kkm.sample"):
+            batch_idx = sample_batch(kb, n, cfg.batch_size)
+        state, info = step(state, x, batch_idx)
         return state, info.improvement
 
     return step_with_key

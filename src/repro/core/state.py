@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kernel_fns import KernelFn, kernel_diag
+from repro.core.loop import scope
 
 
 class CenterState(NamedTuple):
@@ -46,17 +47,18 @@ def init_state(x: jax.Array, center_idx: jax.Array, kernel: KernelFn,
     """Centers start as single data points (k-means++ / random init picks
     indices), occupying slot 0 with coefficient 1."""
     k = center_idx.shape[0]
-    idx = jnp.pad(center_idx.astype(jnp.int32)[:, None],
-                  ((0, 0), (0, window - 1)))
-    coef = jnp.zeros((k, window), jnp.float32).at[:, 0].set(1.0)
-    return CenterState(
-        idx=idx,
-        coef=coef,
-        head=jnp.ones((k,), jnp.int32),
-        sqnorm=kernel_diag(kernel, x[center_idx]).astype(jnp.float32),
-        counts=jnp.zeros((k,), jnp.float32),
-        step=jnp.zeros((), jnp.int32),
-    )
+    with scope("kkm.init"):
+        idx = jnp.pad(center_idx.astype(jnp.int32)[:, None],
+                      ((0, 0), (0, window - 1)))
+        coef = jnp.zeros((k, window), jnp.float32).at[:, 0].set(1.0)
+        return CenterState(
+            idx=idx,
+            coef=coef,
+            head=jnp.ones((k,), jnp.int32),
+            sqnorm=kernel_diag(kernel, x[center_idx]).astype(jnp.float32),
+            counts=jnp.zeros((k,), jnp.float32),
+            step=jnp.zeros((), jnp.int32),
+        )
 
 
 def window_size(batch_size: int, tau: int) -> int:

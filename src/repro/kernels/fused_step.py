@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.kernel_fns import KernelFn, is_index_data, kernel_cross
+from repro.core.loop import scope
 from repro.kernels.fused_assign import _apply_kernel
 
 # Center-chunk width of the XLA fallback: one (b, kc*W) slab live at a
@@ -276,12 +277,15 @@ def streaming_assign_pallas(
     b, d = xb.shape
     k, w, _ = sup.shape
     bp, wp, dp = -b % bt, -w % st, -d % 128
-    xb_p = jnp.pad(xb, ((0, bp), (0, dp)))
-    sup_p = jnp.pad(sup, ((0, 0), (0, wp), (0, dp)))
-    coef_p = jnp.pad(coef.astype(jnp.float32), ((0, 0), (0, wp)))[:, None]
-    diag_p = jnp.pad(diag_b.astype(jnp.float32), (0, bp))[:, None]
-    xsq = jnp.sum(xb_p.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-    supsq = jnp.sum(sup_p.astype(jnp.float32) ** 2, axis=-1)[:, None]
+    with scope("kkm.pad"):
+        xb_p = jnp.pad(xb, ((0, bp), (0, dp)))
+        sup_p = jnp.pad(sup, ((0, 0), (0, wp), (0, dp)))
+        coef_p = jnp.pad(coef.astype(jnp.float32),
+                         ((0, 0), (0, wp)))[:, None]
+        diag_p = jnp.pad(diag_b.astype(jnp.float32), (0, bp))[:, None]
+        xsq = jnp.sum(xb_p.astype(jnp.float32) ** 2, axis=-1,
+                      keepdims=True)
+        supsq = jnp.sum(sup_p.astype(jnp.float32) ** 2, axis=-1)[:, None]
 
     bb, dd = xb_p.shape
     ww = sup_p.shape[1]

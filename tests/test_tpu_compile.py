@@ -130,7 +130,14 @@ def test_fused_fit_step_compiles_for_v5e(one_chip, monkeypatch, precision):
     compiled = jax.jit(step).lower(
         state, _spec(one_chip, (N, D)),
         _spec(one_chip, (B,), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    # the two passes are told apart by their stage scopes, and the
+    # kernel wrapper's padding has its own
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert any("/kkm.assign/" in ln for ln in calls)
+    assert any("/kkm.objective/" in ln for ln in calls)
+    assert "/kkm.pad/" in text
     mem = compiled.memory_analysis()
     hbm = 16 * 2 ** 30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < hbm
