@@ -15,7 +15,10 @@ Two implementations, dispatched by :mod:`repro.kernels.ops`:
   contraction into a (bt, 1) VMEM scratch; at the last window tile the
   center's distances fold into the resident best/argmin output blocks.
   VMEM working set per step: bt*d + st*d + bt*st + O(bt) floats — the
-  (b, k*W) strip and (b, k) distances never touch HBM.  Mixed precision:
+  (b, k*W) strip and (b, k) distances never touch HBM.  The whole
+  support is read from HBM once per batch tile, so :func:`streaming_tiles`
+  makes ``bt`` as large as VMEM allows (the whole padded batch at the
+  paper's widths: one sweep of the support per pass).  Mixed precision:
   ``precision="bf16"`` casts the coordinate tiles to bfloat16 before the
   MXU matmul; the cross products, kernel elementwise math, coefficient
   contraction and argmin carries all stay f32 (the Schwartzman'23 regime:
@@ -38,7 +41,7 @@ Two implementations, dispatched by :mod:`repro.kernels.ops`:
   total width, and the slab distances differ from the strip's by at most
   2 ulp (tests/test_fused_step.py pins both cases).
 
-Tile defaults and the per-backend tuning story live in docs/perf.md.
+Tile choice and the per-backend tuning story live in docs/perf.md.
 """
 from __future__ import annotations
 
@@ -222,6 +225,71 @@ def streamed_sqnorm_pts(kernel: KernelFn, pts: jax.Array, coef: jax.Array,
 
 
 # ---------------------------------------------------------------- Pallas
+# Mosaic gives a kernel 16 MiB of scoped VMEM unless it asks for more
+# (v5e); the tiles may plan for up to _VMEM_BUDGET of the 128 MiB a v5e
+# core holds.
+_SCOPED_VMEM = 16 * 2 ** 20
+_VMEM_BUDGET = 64 * 2 ** 20
+_SUPPORT_TILES = (256, 128)           # widest first
+_LANE = 128
+
+# (b, w, d) -> (bt, st, sweeps) of every plan the kernel traced; written
+# at trace time only
+_STREAMING_PLANS: dict = {}
+
+
+def streaming_plans() -> dict:
+    """Every distinct tile plan the streaming kernel has traced since
+    import, ``(b, w, d) -> (bt, st, sweeps)``; ``sweeps`` is how many
+    times one pass reads the whole support from HBM."""
+    return dict(_STREAMING_PLANS)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _vmem_bytes(bt: int, st: int, dd: int, itemsize: int) -> int:
+    """VMEM of one grid step, counted high: double-buffered x and
+    support blocks, their copies cast for the MXU, the (bt, 1) column
+    blocks and accumulator (each a lane-padded (bt, 128) f32 tile), the
+    (1, st) row blocks (sublane-padded to 8) and three (bt, st) f32
+    intermediates (cross products, kernel values, weighted values).
+    Compiled for a v5e, (2048, 256) at d=784 needs a scoped limit of
+    about 6 MiB against this 34.7 MiB; (8192, 128) at d=64 needs more
+    than the 16 MiB default."""
+    blocks = 2 * (bt + st) * dd * itemsize + (bt + st) * dd * 2
+    cols = (2 * 4 + 1) * bt * _LANE * 4
+    rows = 2 * 2 * 8 * st * 4
+    return blocks + cols + rows + 3 * bt * st * 4
+
+
+def streaming_tiles(b: int, w: int, d: int, itemsize: int = 4):
+    """-> (bt, st, sweeps, vmem_bytes) for a (b, d) batch against
+    windows of w support rows.
+
+    The support is read from HBM once per batch tile, so ``bt`` takes
+    the whole padded batch where the working set fits _VMEM_BUDGET,
+    else the fewest tiles that do.  The padded batch is never longer
+    than the 128-row round-up, and ``st`` never pads w past its 128
+    round-up.  Where no tiling fits, the 128-row tiles remain."""
+    sub = 8 * 4 // min(itemsize, 4)   # sublanes of one packed VMEM tile
+    dd = _round_up(d, _LANE)
+    b128 = _round_up(b, _LANE)
+    sts = [t for t in _SUPPORT_TILES
+           if _round_up(w, t) == _round_up(w, _LANE)]
+    for n in range(1, b128 // _LANE + 1):
+        bt = _round_up(-(-b // n), sub)
+        if n * bt > b128:
+            continue
+        for st in sts:
+            vmem = _vmem_bytes(bt, st, dd, itemsize)
+            if vmem <= _VMEM_BUDGET:
+                return bt, st, -(-b // bt), vmem
+    bt = min(_LANE, _round_up(b, sub))
+    return bt, _LANE, -(-b // bt), _vmem_bytes(bt, _LANE, dd, itemsize)
+
+
 def _stream_body(sqn_ref, x_ref, xsq_ref, diag_ref, sup_ref, supsq_ref,
                  coef_ref, best_ref, idx_ref, p_acc, *, kind, p0, p1, p2,
                  bf16):
@@ -260,11 +328,13 @@ def _stream_body(sqn_ref, x_ref, xsq_ref, diag_ref, sup_ref, supsq_ref,
 def streaming_assign_pallas(
         xb: jax.Array, sup: jax.Array, coef: jax.Array, sqnorm: jax.Array,
         diag_b: jax.Array, *, kind: str = "gaussian", p0: float = 1.0,
-        p1: float = 1.0, p2: int = 2, bt: int = 128, st: int = 128,
-        bf16: bool = False, interpret: bool = False):
+        p1: float = 1.0, p2: int = 2, bt: int | None = None,
+        st: int | None = None, bf16: bool = False,
+        interpret: bool = False):
     """xb (b, d); sup (k, W, d); coef (k, W); sqnorm (k,); diag_b (b,)
     -> (best (b,) f32, assign (b,) int32).
 
+    ``bt`` / ``st`` left ``None`` come from :func:`streaming_tiles`.
     b / W / d are padded to tile multiples (zero support points with zero
     coefficients contribute nothing; padded batch rows are sliced off).
     Mosaic layout: per-center rows (support norms, coefficients) are
@@ -276,7 +346,13 @@ def streaming_assign_pallas(
 
     b, d = xb.shape
     k, w, _ = sup.shape
-    bp, wp, dp = -b % bt, -w % st, -d % 128
+    itemsize = max(xb.dtype.itemsize, sup.dtype.itemsize)
+    rule_bt, rule_st, _, _ = streaming_tiles(b, w, d, itemsize)
+    bt = rule_bt if bt is None else bt
+    st = rule_st if st is None else st
+    _STREAMING_PLANS[(b, w, d)] = (bt, st, -(-b // bt))
+    vmem = _vmem_bytes(bt, st, _round_up(d, _LANE), itemsize)
+    bp, wp, dp = -b % bt, -w % st, -d % _LANE
     with scope("kkm.pad"):
         xb_p = jnp.pad(xb, ((0, bp), (0, dp)))
         sup_p = jnp.pad(sup, ((0, 0), (0, wp), (0, dp)))
@@ -313,7 +389,8 @@ def streaming_assign_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem if vmem > _SCOPED_VMEM else None),
         interpret=interpret,
     )(sqnorm.astype(jnp.float32), xb_p, xsq, diag_p, sup_p, supsq, coef_p)
     return best[:b, 0], idx[:b, 0]

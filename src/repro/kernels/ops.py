@@ -97,14 +97,15 @@ def _streaming_dispatch(kernel: KernelFn, interpret):
 def streaming_assign(kernel: KernelFn, xb: jax.Array, sup_flat: jax.Array,
                      coef: jax.Array, sqnorm: jax.Array,
                      diag_b: jax.Array, *, precision: str = "f32",
-                     bt: int = 128, st: int = 128,
+                     bt: int | None = None, st: int | None = None,
                      kc: int = fused_step.STREAM_CHUNK,
                      interpret=None):
     """Streaming fused assignment: (best_dist (b,), assign (b,) int32)
     over all k centers without materializing the (b, k*W) cross strip or
     the (b, k) distances — the `step="fused"` hot pass.
     ``sup_flat``: (k*W, d) support rows (index-data rows for cached /
-    precomputed kernels)."""
+    precomputed kernels).  Tiles left ``None`` come from
+    :func:`fused_step.streaming_tiles`."""
     k, w = coef.shape
     sup = sup_flat.reshape(k, w, sup_flat.shape[-1])
     disp, interpret = _streaming_dispatch(kernel, interpret)
@@ -121,7 +122,8 @@ def streaming_assign(kernel: KernelFn, xb: jax.Array, sup_flat: jax.Array,
 
 def streaming_min(kernel: KernelFn, xb: jax.Array, sup_flat: jax.Array,
                   coef: jax.Array, sqnorm: jax.Array, diag_b: jax.Array,
-                  *, precision: str = "f32", bt: int = 128, st: int = 128,
+                  *, precision: str = "f32", bt: int | None = None,
+                  st: int | None = None,
                   kc: int = fused_step.STREAM_CHUNK, interpret=None):
     """Streaming min distance (b,) only — the fused step's post-update
     objective pass (assignment indices not needed)."""

@@ -169,6 +169,7 @@ def test_streamed_sqnorm_bit_identical_to_recompute():
     (32, 4, 48, 8, 8, 8),      # several window tiles per center
     (17, 3, 21, 5, 8, 24),     # unaligned everything, one window tile
     (64, 8, 40, 16, 16, 16),   # bt < b, st < w
+    (300, 3, 300, 20, None, 256),  # the rule's tile: the whole batch
 ])
 def test_streaming_pallas_interpret_matches_fallback(kname, b, k, w, d,
                                                      bt, st):
@@ -188,6 +189,32 @@ def test_streaming_pallas_interpret_matches_fallback(kname, b, k, w, d,
     # index mismatch only where the two best distances are this close
     idx_ok = np.asarray(got_idx) == np.asarray(want_idx)
     assert np.mean(idx_ok) > 0.99, np.mean(idx_ok)
+    if bt is None:
+        assert fs.streaming_plans()[(b, w, d)] == (-(-b // 8) * 8, st, 1)
+
+
+@pytest.mark.parametrize("itemsize", [4, 2])
+@pytest.mark.parametrize("b,w,d,want", [
+    (2048, 2248, 784, (2048, 256, 1)),    # mnist_rbf: one sweep
+    (2048, 4096, 1024, (2048, 256, 1)),   # paper_cluster, one chip
+    (17, 21, 5, None),                    # an unaligned per-shard shape
+    (8192, 2248, 784, (4096, 128, 2)),    # past the VMEM budget at f32
+])
+def test_streaming_tiles(b, w, d, want, itemsize):
+    bt, st, sweeps, vmem = fs.streaming_tiles(b, w, d, itemsize)
+    sub = 8 * 4 // itemsize
+    r128 = -(-b // 128) * 128
+    assert bt % sub == 0 and sweeps == -(-b // bt)
+    assert b <= bt * sweeps <= r128       # never more padding than bt=128
+    assert -(-w // st) * st == -(-w // 128) * 128
+    assert vmem <= fs._VMEM_BUDGET
+    assert vmem == fs._vmem_bytes(bt, st, -(-d // 128) * 128, itemsize)
+    if want is None:                      # one tile, the padded batch
+        assert (bt, sweeps) == (-(-b // sub) * sub, 1)
+    elif itemsize == 4:
+        assert (bt, st, sweeps) == want
+    else:                                 # half the bytes: no more sweeps
+        assert sweeps <= want[2]
 
 
 def test_streaming_pallas_bf16_mode_close_to_f32():

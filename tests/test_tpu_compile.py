@@ -18,6 +18,8 @@ from jax.sharding import SingleDeviceSharding
 K, D, B = 256, 1024, 2048
 W = 2 * B
 N = 2 ** 18
+# mnist_rbf widths: b=2048, tau=200, d and W off the 128 tiles
+MNIST = dict(k=10, w=2248, d=784, b=2048)
 
 
 @pytest.fixture(scope="module")
@@ -72,6 +74,14 @@ def _kernel_case(name):
         return (lambda *a: streaming_assign_pallas(*a, kind="gaussian",
                                                    p0=2.0, bf16=True),
                 [(s, f32) for s in stream])
+    if name in ("streaming_assign_mnist", "streaming_assign_mnist_b8192"):
+        # the larger batch takes two tiles of 4096 rows, past the
+        # default scoped VMEM
+        k, w, d, b = MNIST["k"], MNIST["w"], MNIST["d"], MNIST["b"]
+        b = 8192 if name.endswith("b8192") else b
+        return (lambda *a: streaming_assign_pallas(*a, kind="gaussian",
+                                                   p0=145.6),
+                [(s, f32) for s in ((b, d), (k, w, d), (k, w), (k,), (b,))])
     if name == "fused_batch_center_dots_f32":
         return (lambda *a: fused_batch_center_dots_pallas(
             *a, kind="gaussian", p0=2.0),
@@ -95,6 +105,7 @@ def _kernel_case(name):
 
 @pytest.mark.parametrize("name", [
     "streaming_assign_f32", "streaming_assign_bf16",
+    "streaming_assign_mnist", "streaming_assign_mnist_b8192",
     "fused_batch_center_dots_f32", "fused_batch_center_dots_bf16",
     "cached_assign_dots", "kernel_matmul",
 ])
