@@ -29,6 +29,7 @@ per-step Python overhead (see ``benchmarks/run.py api_overhead``).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -166,11 +167,20 @@ class SingleExecutor(Executor):
     supports_partial_fit = True
 
     def _ensure_host_step(self):
-        # donate the carried CenterState — the host loop threads it
-        return self._program(
-            ("host_step", self.mb, ("donate", 0)),
-            lambda: jax.jit(make_step(self.kernel, self.mb),
-                            donate_argnums=(0,)))
+        # donate the carried CenterState — the host loop threads it; a
+        # kernel too large to key by value is a traced argument, as in
+        # _jit_run
+        mb, kernel = self.mb, self.kernel
+        if _kernel_sig(kernel) is not None:
+            return self._program(
+                ("host_step", mb, ("donate", 0)),
+                lambda: jax.jit(make_step(kernel, mb), donate_argnums=(0,)))
+        prog = self._program(
+            ("host_step", mb, ("donate", 1), True),
+            lambda: jax.jit(lambda kern, state, x, bidx: make_step(
+                kern, mb)(state, x, bidx), donate_argnums=(1,)),
+            kernel_free=True)
+        return functools.partial(prog, kernel)
 
     def _jit_run(self, kind: str, max_iters: int):
         kernel = self.kernel
@@ -182,24 +192,28 @@ class SingleExecutor(Executor):
         # NOTHING: its key/init_idx can be caller-owned buffers (the
         # legacy shims pass the user's raw key), which callers may reuse.
         donate = () if kind == "init" else (1, 2)
+        # a kernel too large to key by value (a Precomputed Gram) is the
+        # program's first, traced argument: closed over, it would be
+        # baked into the program as a constant
+        as_arg = _kernel_sig(kernel) is None
 
         def build():
-            step = make_step(kernel, mb)
+            def run(kern, x, start, key):
+                step = make_step(kern, mb)
+                state0 = (init_state(x, start, kern, w) if kind == "init"
+                          else start)
+                return run_early_stopped_keyed(
+                    mb, sampled_step_with_key(step, x, mb), state0, key)
 
-            if kind == "init":
-                def run(x, init_idx, key):
-                    state0 = init_state(x, init_idx, kernel, w)
-                    return run_early_stopped_keyed(
-                        mb, sampled_step_with_key(step, x, mb), state0,
-                        key)
-            else:
-                def run(x, state, key):
-                    return run_early_stopped_keyed(
-                        mb, sampled_step_with_key(step, x, mb), state, key)
+            if as_arg:
+                return jax.jit(run, donate_argnums=tuple(
+                    i + 1 for i in donate))
+            return jax.jit(functools.partial(run, kernel),
+                           donate_argnums=donate)
 
-            return jax.jit(run, donate_argnums=donate)
-
-        return self._program((kind, mb, ("donate",) + donate), build)
+        prog = self._program((kind, mb, ("donate",) + donate, as_arg),
+                             build, kernel_free=as_arg)
+        return functools.partial(prog, kernel) if as_arg else prog
 
     def _use_jit(self, sample_weight):
         return (self.config.jit and sample_weight is None

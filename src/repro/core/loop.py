@@ -251,11 +251,12 @@ def program_builds() -> int:
 # on-device loop, the batch draw, and the Algorithm-2 step's gathers,
 # assignment pass, rates and ring append, <C_j, C_j> recompute, objective
 # pass, and the streaming kernel's padding; ``kkm.init`` also names the
-# init's device ops.  A device op's stage is the innermost ``kkm.*``
-# component of its scope path.
+# init's device ops, and ``kkm.gram`` (span and stage) the k-nn Gram build
+# of ``repro.data.graph_kernels``.  A device op's stage is the innermost
+# ``kkm.*`` component of its scope path.
 STAGES = ("kkm.fit", "kkm.init", "kkm.run", "kkm.loop", "kkm.sample",
           "kkm.gather", "kkm.assign", "kkm.update", "kkm.sqnorm",
-          "kkm.objective", "kkm.pad")
+          "kkm.objective", "kkm.pad", "kkm.gram")
 
 
 def span(name: str):

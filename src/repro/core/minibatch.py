@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.api import keys as api_keys
 from repro.core.kernel_fns import (
-    KernelFn, diag_of, gram_rows_fn, kernel_cross,
+    KernelFn, Precomputed, diag_of, gram_rows_fn, kernel_cross,
 )
 from repro.core.loop import (  # noqa: F401  (re-exported loop-core names)
     compress_hook, drive_fit_loop, precision_plan, run_early_stopped,
@@ -131,10 +131,13 @@ def _sqnorm_recompute(kernel, x, idx, coef, cdt=None):
     resolve all k*W support rows in ONE lookup outside the vmap and gather
     the per-center W x W blocks inside it — a cached lookup placed under
     the per-center vmap would lower its ``lax.cond`` to ``select`` and run
-    the miss branch (a full strip recompute) on every hit.
+    the miss branch (a full strip recompute) on every hit.  A
+    ``Precomputed`` Gram takes :func:`_gram_sqnorm`.
 
     ``cdt``: optional compute dtype for the Gram COORDINATES (the fused
     step's bf16 mode); coefficients and the quadratic form stay f32."""
+    if isinstance(kernel, Precomputed):
+        return _gram_sqnorm(kernel, x, idx, coef)
     rows_fn = gram_rows_fn(kernel)
     if rows_fn is not None:
         k, w = idx.shape
@@ -157,6 +160,63 @@ def _sqnorm_recompute(kernel, x, idx, coef, cdt=None):
         return coef_row @ (g @ coef_row)
 
     return jax.vmap(one)(idx, coef)
+
+
+def _gram_ids(x, idx):
+    """Gram column ids (k, W) of the windows' dataset rows: index data
+    holds each row's id into the Gram."""
+    return x[idx.reshape(-1), 0].astype(jnp.int32).reshape(idx.shape)
+
+
+def sqnorm_route(k: int, w: int, n: int) -> str:
+    """How :func:`_gram_sqnorm` reads an n x n Gram for k windows of W
+    slots: ``"blocks"``, the k (W, W) blocks, while the k W support rows
+    are fewer than the Gram's n; else ``"gram"``, one pass of the whole
+    Gram, which then reads no more rows than the support has."""
+    return "blocks" if k * w < n else "gram"
+
+
+def _gram_sqnorm(kernel: Precomputed, x, idx, coef):
+    """<C_j, C_j> = V_j G V_j^T from a ``Precomputed`` Gram G, every
+    product at ``Precision.HIGHEST``, by :func:`sqnorm_route`:
+
+    * ``"blocks"``: gather each center's (W, W) block of G, then
+      c_j^T (G_j c_j);
+    * ``"gram"``: scatter the windows into dense weights V (k, n)
+      (duplicate ids add, as in the window sum), one pass of G against
+      V^T — through the Gram-row kernel where it compiles natively — and
+      <C_j, C_j> = sum_c V[j, c] (G V^T)[c, j]."""
+    from repro.kernels import ops as kops
+    from repro.kernels.cached_gather import window_weights
+
+    hi = jax.lax.Precision.HIGHEST
+    gram = kernel.gram
+    k, w = idx.shape
+    ids = _gram_ids(x, idx)
+    if sqnorm_route(k, w, gram.shape[0]) == "blocks":
+        def one(ids_j, c_j):
+            g = gram[ids_j][:, ids_j]                              # (W, W)
+            return jnp.dot(c_j, jnp.dot(g, c_j, precision=hi), precision=hi)
+
+        return jax.vmap(one)(ids, coef)
+    with scope("kkm.gather"):
+        v = window_weights(ids, coef, gram.shape[1])               # (k, n)
+    gv = (kops.gram_row_dots(gram, v) if kops.native()
+          else jnp.dot(gram, v.T, precision=hi))                   # (n, k)
+    return jnp.sum(v.T * gv, axis=0)
+
+
+def _gram_pass(kernel: Precomputed, x, rows, idx, coef):
+    """P = <phi(x_B), C_j> from the batch's Gram rows (b, n): the windows
+    scattered into dense weights V (k, n), then rows @ V^T through the
+    Gram-row kernel on the MXU at ``Precision.HIGHEST``
+    (:mod:`repro.kernels.cached_gather`) — no (b, k*W) strip."""
+    from repro.kernels import ops as kops
+    from repro.kernels.cached_gather import window_weights
+
+    with scope("kkm.gather"):
+        v = window_weights(_gram_ids(x, idx), coef, rows.shape[1])
+    return kops.gram_row_dots(rows, v)
 
 
 def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
@@ -191,6 +251,17 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
     # lookups with zero memory win.  They take the composed passes below.
     prec = precision_plan(kernel, cfg)
     index_data, precision, cdt = prec.index_data, prec.tag, prec.cdt
+    # a Precomputed Gram where the kernels compile natively (TPU): both
+    # passes contract the batch's Gram rows, gathered once per step, with
+    # the windows' dense weights on the MXU (_gram_pass).  Elsewhere the
+    # composed dots below run: the trajectory the CPU tests hold the
+    # kernel to.
+    gram_pass = isinstance(kernel, Precomputed) and kops.native()
+
+    def index_dots(xb, x, rows, idx, coef):
+        if gram_pass:
+            return _gram_pass(kernel, x, rows, idx, coef)
+        return _batch_center_dots(kernel, xb, x, idx, coef, cfg.use_pallas)
 
     def step(state: CenterState, x: jax.Array, batch_idx: jax.Array):
         k, w = state.idx.shape
@@ -198,6 +269,8 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
             xb = x[batch_idx]                                      # (b, d)
             diag_b = diag_of(kernel, xb)                          # (b,)
             sup = None if index_data else x[state.idx.reshape(-1)]
+            rows = (kernel.gram[xb[:, 0].astype(jnp.int32)]       # (b, n)
+                    if gram_pass else None)
 
         # ---- (2) streaming assignment: online argmin over centers ---------
         with scope("kkm.assign"):
@@ -206,8 +279,7 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
                 # dots), then min/argmin — per-slab lookups would re-run
                 # the cache's key scan k/kc times for values that are
                 # gathers
-                p = _batch_center_dots(kernel, xb, x, state.idx,
-                                       state.coef, cfg.use_pallas)
+                p = index_dots(xb, x, rows, state.idx, state.coef)
                 dists = diag_b[:, None] - 2.0 * p + state.sqnorm[None, :]
                 best = jnp.min(dists, axis=1)
                 assign = jnp.argmin(dists, axis=1).astype(jnp.int32)
@@ -247,8 +319,7 @@ def _make_fused_step(kernel: KernelFn, cfg: MBConfig):
             new_sup = None if index_data else x[new_idx.reshape(-1)]
         with scope("kkm.objective"):
             if index_data:
-                p_new = _batch_center_dots(kernel, xb, x, new_idx, new_coef,
-                                           cfg.use_pallas)
+                p_new = index_dots(xb, x, rows, new_idx, new_coef)
                 d_new = diag_b[:, None] - 2.0 * p_new + new_sqnorm[None, :]
                 best2 = jnp.min(d_new, axis=1)
             else:

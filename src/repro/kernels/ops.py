@@ -15,7 +15,9 @@ from repro.core.kernel_fns import (
     Gaussian, KernelFn, Linear, Polynomial,
 )
 from repro.kernels import fused_step, ref
-from repro.kernels.cached_gather import cached_assign_dots_pallas
+from repro.kernels.cached_gather import (
+    cached_assign_dots_pallas, window_weights,
+)
 from repro.kernels.fused_assign import fused_batch_center_dots_pallas
 from repro.kernels.kernel_matmul import kernel_matmul_pallas
 
@@ -69,18 +71,33 @@ def fused_batch_center_dots(kernel: KernelFn, xb: jax.Array,
 
 
 def cached_assign_dots(rows: jax.Array, sup_ids: jax.Array,
-                       coef: jax.Array, bt: int = 128, nt: int = 512,
-                       interpret=None) -> jax.Array:
+                       coef: jax.Array, bt: int | None = None,
+                       nt: int = 512, interpret=None) -> jax.Array:
     """P[i,j] = sum_w coef[j,w] rows[i, sup_ids[j,w]] — the assignment
-    contraction over cache-resolved Gram rows (no kernel evaluations; the
+    contraction over full Gram rows (no kernel evaluations; the
     gather-from-cache tile kernel of the repro.cache subsystem)."""
+    return gram_row_dots(rows, window_weights(sup_ids, coef, rows.shape[1]),
+                         bt=bt, nt=nt, interpret=interpret)
+
+
+def gram_row_dots(rows: jax.Array, v: jax.Array, bt: int | None = None,
+                  nt: int = 512, interpret=None) -> jax.Array:
+    """rows @ v^T, (b, k), for Gram rows (b, n) and window weights
+    v (k, n) (:func:`repro.kernels.cached_gather.window_weights`), on the
+    MXU at ``Precision.HIGHEST``."""
     if interpret is None:
         interpret = _interpret_default()
     if interpret:
-        bt = _clamp_tile(bt, rows.shape[0], 8)
+        bt = _clamp_tile(bt or 128, rows.shape[0], 8)
         nt = _clamp_tile(nt, rows.shape[1], 8)
-    return cached_assign_dots_pallas(rows, sup_ids, coef, bt=bt, nt=nt,
+    return cached_assign_dots_pallas(rows, v, bt=bt, nt=nt,
                                      interpret=interpret)
+
+
+def native() -> bool:
+    """Whether the Pallas kernels compile natively on this backend (a
+    TPU).  Paths with an XLA twin take the twin everywhere else."""
+    return not _interpret_default()
 
 
 def _streaming_dispatch(kernel: KernelFn, interpret):

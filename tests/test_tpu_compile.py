@@ -20,6 +20,8 @@ W = 2 * B
 N = 2 ** 18
 # mnist_rbf widths: b=2048, tau=200, d and W off the 128 tiles
 MNIST = dict(k=10, w=2248, d=784, b=2048)
+# pendigits_knn: the k-nn Gram of n=10,992 rows, k=10, b=2048, tau=200
+PENDIGITS = dict(k=10, w=2248, n=10992, b=2048)
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +66,7 @@ def _kernel_case(name):
     from repro.kernels.fused_step import streaming_assign_pallas
     from repro.kernels.kernel_matmul import kernel_matmul_pallas
 
-    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    f32, bf16 = jnp.float32, jnp.bfloat16
     stream = ((B, D), (K, W, D), (K, W), (K,), (B,))
     if name == "streaming_assign_f32":
         return (lambda *a: streaming_assign_pallas(*a, kind="gaussian",
@@ -92,10 +94,15 @@ def _kernel_case(name):
             [((B, D), bf16), ((K, W, D), bf16), ((K, W), f32)])
     if name == "cached_assign_dots":
         # an index-data shape of the cached plans: b=1024 Gram rows over
-        # n=65,536 points, k=64 windows of W=2048
+        # n=65,536 points against the dense weights of k=64 windows
         return (lambda *a: cached_assign_dots_pallas(*a),
-                [((1024, 65536), f32), ((64, 2048), i32),
-                 ((64, 2048), f32)])
+                [((1024, 65536), f32), ((64, 65536), f32)])
+    if name == "cached_assign_dots_pendigits":
+        # the k-nn Gram's passes: n=10,992, off the 512 lane tile, with
+        # 2048 batch rows and the whole Gram for the recompute
+        n, k = PENDIGITS["n"], PENDIGITS["k"]
+        return (lambda *a: cached_assign_dots_pallas(*a),
+                [((n, n), f32), ((k, n), f32)])
     if name == "kernel_matmul":
         return (lambda *a: kernel_matmul_pallas(*a, kind="gaussian",
                                                 p0=2.0),
@@ -107,7 +114,7 @@ def _kernel_case(name):
     "streaming_assign_f32", "streaming_assign_bf16",
     "streaming_assign_mnist", "streaming_assign_mnist_b8192",
     "fused_batch_center_dots_f32", "fused_batch_center_dots_bf16",
-    "cached_assign_dots", "kernel_matmul",
+    "cached_assign_dots", "cached_assign_dots_pendigits", "kernel_matmul",
 ])
 def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name)
@@ -152,3 +159,36 @@ def test_fused_fit_step_compiles_for_v5e(one_chip, monkeypatch, precision):
     mem = compiled.memory_analysis()
     hbm = 16 * 2 ** 30
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < hbm
+
+
+def test_fused_index_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The fused step on index data at pendigits_knn's shapes: the Gram
+    is an argument, both passes and the recompute (k W = 22,480 >= n, so
+    one pass of the whole Gram) run the Gram-row kernel, the batch's Gram
+    rows are the only (b, n) buffer, and no (b, k W) strip exists."""
+    from repro.core.kernel_fns import Precomputed
+    from repro.core.minibatch import MBConfig, make_step, sqnorm_route
+    from repro.core.state import CenterState
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    k, w, n, b = (PENDIGITS[f] for f in ("k", "w", "n", "b"))
+    assert sqnorm_route(k, w, n) == "gram"
+    cfg = MBConfig(k=k, batch_size=b, tau=w - b, step="fused")
+    state = CenterState(
+        idx=_spec(one_chip, (k, w), jnp.int32),
+        coef=_spec(one_chip, (k, w)), head=_spec(one_chip, (k,), jnp.int32),
+        sqnorm=_spec(one_chip, (k,)), counts=_spec(one_chip, (k,)),
+        step=_spec(one_chip, (), jnp.int32))
+    compiled = jax.jit(lambda kern, st, x, bidx: make_step(kern, cfg)(
+        st, x, bidx)).lower(
+        Precomputed(gram=_spec(one_chip, (n, n))), state,
+        _spec(one_chip, (n, 1)), _spec(one_chip, (b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    for stage in ("/kkm.assign/", "/kkm.objective/", "/kkm.sqnorm/"):
+        assert sum(stage in ln for ln in calls) == 1, stage
+    assert f"f32[{b},{k * w}]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 2 * 2 ** 30

@@ -81,3 +81,22 @@ def test_fit_program_carries_every_stage(step):
     assert "jit(run)/kkm.init/" in text
     for stage in STEP_STAGES:
         assert f"kkm.loop/while/body/{stage}/" in text, stage
+
+
+def test_gram_build_records_its_span_and_stage(tmp_path):
+    """The k-nn Gram build: a ``kkm.gram`` host span around its dispatch
+    and every device op of its program under the ``kkm.gram`` stage."""
+    from repro.data import graph_kernels
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (64, 4))
+    text = graph_kernels._knn_gram.lower(x, k=5, block=16).as_text(
+        debug_info=True)
+    assert "kkm.gram/" in text
+    graph_kernels.knn_kernel(x, k=5)            # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        kern, _ = graph_kernels.knn_kernel(x, k=5)
+        jax.block_until_ready(kern.gram)
+    finally:
+        jax.profiler.stop_trace()
+    assert [s[0] for s in _host_spans(str(tmp_path))] == ["kkm.gram"]
