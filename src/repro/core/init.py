@@ -14,11 +14,13 @@ the canonical initial center.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.kernel_fns import KernelFn, kernel_cross, kernel_diag
-from repro.core.loop import scope, span
+from repro.core.loop import _kernel_sig, lookup_program, scope, span
 
 
 def kmeans_plus_plus(key: jax.Array, x: jax.Array, k: int,
@@ -69,15 +71,50 @@ def random_init(key: jax.Array, n: int, k: int) -> jax.Array:
     return jax.random.choice(key, n, (k,), replace=False).astype(jnp.int32)
 
 
+def _draw(method: str, k: int, kernel: KernelFn, key: jax.Array,
+          x: jax.Array) -> jax.Array:
+    with scope("kkm.init"):
+        if method == "kmeans++":
+            return kmeans_plus_plus(key, x, k, kernel)
+        return random_init(key, x.shape[0], k)
+
+
+def _draw_program(k: int, kernel: KernelFn, method: str):
+    """The compiled draw ``(key, x) -> (k,) int32`` for (method, k, kernel
+    signature), built once through the loop core's program registry and
+    reused across fits (``jit`` keys x's shape, dtype and sharding).  As
+    for the fit program (``SingleExecutor._jit_run``), a kernel whose
+    leaves key by value is closed over, its scalars compile-time
+    constants; a kernel too large to key by value (a Precomputed Gram, a
+    cached kernel) is the program's first, traced argument, so a new Gram
+    of the same shape reuses the program and is never baked in."""
+    as_arg = _kernel_sig(kernel) is None
+
+    def build():
+        if as_arg:
+            def draw(kern, key, x):
+                return _draw(method, k, kern, key, x)
+        else:
+            def draw(key, x):
+                return _draw(method, k, kernel, key, x)
+        return jax.jit(draw)
+
+    # an empty instance cache: the registry's key carries the kernel
+    # signature, so a draw for one kappa never serves another
+    prog = lookup_program({}, "draw_init", (method, k, as_arg), build,
+                          kernel=kernel, kernel_free=as_arg)
+    return functools.partial(prog, kernel) if as_arg else prog
+
+
 def draw_init(key: jax.Array, x: jax.Array, k: int, kernel: KernelFn,
               method: str = "kmeans++") -> jax.Array:
     """The one init-drawing entry every fit path shares (it used to be
     copy-pasted across ``fit`` / ``fit_cached`` / the engine): dispatch on
-    the method name, return (k,) int32 indices into ``x``."""
-    with span("kkm.init"), scope("kkm.init"):
-        if method == "kmeans++":
-            return kmeans_plus_plus(key, x, k, kernel)
-        if method == "random":
-            return random_init(key, x.shape[0], k)
-    raise ValueError(f"unknown init method {method!r} "
-                     "(expected 'kmeans++' or 'random')")
+    the method name, return (k,) int32 indices into ``x``.  The draw is a
+    compiled program (:func:`_draw_program`), so a fit only dispatches it
+    and the device runs it and the fit program back to back."""
+    if method not in ("kmeans++", "random"):
+        raise ValueError(f"unknown init method {method!r} "
+                         "(expected 'kmeans++' or 'random')")
+    with span("kkm.init"):
+        return _draw_program(k, kernel, method)(key, x)

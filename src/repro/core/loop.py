@@ -304,9 +304,9 @@ def _kernel_sig(kernel):
     leaves, treedef = jax.tree_util.tree_flatten(kernel)
     sig = []
     for leaf in leaves:
-        a = np.asarray(leaf)
-        if a.size > 64:
+        if np.size(leaf) > 64:          # sized before any copy to the host
             return None
+        a = np.asarray(leaf)
         sig.append((a.dtype.str, a.shape, a.tobytes()))
     return (treedef, tuple(sig))
 

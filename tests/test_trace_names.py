@@ -83,6 +83,31 @@ def test_fit_program_carries_every_stage(step):
         assert f"kkm.loop/while/body/{stage}/" in text, stage
 
 
+@pytest.mark.parametrize("method", ["kmeans++", "random"])
+@pytest.mark.parametrize("large", [False, True])
+def test_init_draw_carries_its_stage(method, large):
+    """The compiled init draw names its device ops ``kkm.init``, whether
+    the kernel is closed over or (too large to key by value) an
+    argument."""
+    from repro.core import init
+    from repro.core.kernel_fns import Gaussian, Precomputed
+
+    x = jnp.zeros((64, 8))
+    key = jax.random.PRNGKey(0)
+    if large:
+        kernel = Precomputed(gram=jnp.eye(64))
+        prog = init._draw_program(4, kernel, method)
+        lowered = prog.func.lower(kernel, key, x[:, :1])
+    else:
+        prog = init._draw_program(4, Gaussian(kappa=jnp.float32(2.0)),
+                                  method)
+        lowered = prog.lower(key, x)
+    text = lowered.as_text(debug_info=True)
+    assert "jit(draw)/kkm.init/" in text
+    if method == "kmeans++":
+        assert "jit(draw)/kkm.init/while/body/" in text
+
+
 def test_gram_build_records_its_span_and_stage(tmp_path):
     """The k-nn Gram build: a ``kkm.gram`` host span around its dispatch
     and every device op of its program under the ``kkm.gram`` stage."""
