@@ -283,8 +283,7 @@ class KernelKMeans:
             return {"note": f"plan {plan.name!r} builds its step program "
                             "inside fit (dataset / tile-cache / mesh "
                             "closure); fit once and inspect "
-                            "program_builds() or benchmarks/run.py "
-                            "instead"}
+                            "program_builds() instead"}
         from repro.core.minibatch import make_step
         from repro.core.state import init_state, window_size
         from repro.launch.analysis import analyze_compiled

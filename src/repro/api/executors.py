@@ -25,7 +25,8 @@ Executors are stateful on purpose: they cache the compiled programs
 (jitted step / while_loop run) across ``fit`` calls — instance-local plus
 the cross-executor registry in the loop core (``lookup_program``), which
 is what makes ``KernelKMeans`` dispatch resolve at trace time with zero
-per-step Python overhead (see ``benchmarks/run.py api_overhead``).
+per-step Python overhead (``tests/test_fused_step.py`` pins the reuse of
+one executable across repeat fits).
 """
 from __future__ import annotations
 
@@ -40,7 +41,7 @@ from repro.api.config import SolverConfig
 from repro.core import init as init_lib
 from repro.core.loop import (  # noqa: F401  (canonical home: the loop core;
     # re-exported here for the historical import surface — estimator,
-    # service snapshot/telemetry, benchmarks and tests all import these
+    # service snapshot/telemetry and tests all import these
     # names from repro.api.executors)
     FitCarry, FitOutcome, LoopSpec, _kernel_sig, _x_keyed_run, carry_of,
     clear_program_cache, lookup_program, outcome_from_carry, program_builds,
@@ -892,10 +893,10 @@ class FusedRestartExecutor(Executor):
             pad_fill: float = 0.0, **kw) -> FitOutcome:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.core.distributed import init_dist_state, pad_for_mesh
+        from repro.core.distributed import (
+            fused_state_placements, init_dist_state, pad_for_mesh,
+            restart_placements)
         from repro.core.minibatch import sample_batch
-        from repro.launch.sharding import (
-            fused_state_placements, restart_placements)
 
         cfg = self.config
         if not cfg.jit:
@@ -940,9 +941,8 @@ class FusedRestartExecutor(Executor):
                     xspec, pad_fill):
         from repro.cache.cached_kernel import make_cached
         from repro.core.distributed import (
-            init_dist_state, init_shard_caches, pad_for_mesh)
-        from repro.launch.sharding import (
-            fused_state_placements, restart_placements)
+            fused_state_placements, init_dist_state, init_shard_caches,
+            pad_for_mesh, restart_placements)
 
         cfg = self.config
         cache_dtype = jnp.dtype(cfg.cache_dtype)

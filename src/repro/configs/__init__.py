@@ -1,4 +1,4 @@
-"""Architecture registry: --arch <id> resolves here."""
+"""Architecture registry: `get_config(<id>)` resolves an id here."""
 from __future__ import annotations
 
 import importlib

@@ -2,8 +2,8 @@
 mini-batch kernel k-means service (repro.core.distributed) at cluster scale
 — e.g. clustering LM hidden states / embedding tables.
 
-This is the (arch = paper technique) cell of the dry-run: the step lowered
-is one Algorithm-2 iteration on the production mesh."""
+``chip_smoke.py`` and ``tests/test_tpu_compile.py`` drive the fit at
+these widths."""
 from repro.core.minibatch import MBConfig
 
 # Production-scale clustering: 256 centers over d=1024 embeddings,

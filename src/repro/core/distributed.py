@@ -27,7 +27,7 @@ single-device implementation on a CPU mesh.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +85,40 @@ def state_shardings(mesh: Mesh, model_axis: str = "model"):
         sqnorm=NamedSharding(mesh, P(m)),
         counts=NamedSharding(mesh, P(m)),
         step=NamedSharding(mesh, P()))
+
+
+def restart_placements(mesh: Mesh, restart_axis: str, sharded: Any,
+                       replicated: Any = None):
+    """Placements for the multi-restart clustering engine: every leaf of
+    ``sharded`` has its leading (restart) axis split over ``restart_axis``;
+    every leaf of ``replicated`` is broadcast to all devices.  Returns the
+    device_put trees (sharded_tree, replicated_tree)."""
+
+    def shard_one(a):
+        spec = P(restart_axis, *([None] * (a.ndim - 1)))
+        return jax.device_put(a, NamedSharding(mesh, spec))
+
+    sh = jax.tree.map(shard_one, sharded)
+    rep = (jax.tree.map(
+        lambda a: jax.device_put(a, NamedSharding(mesh, P())), replicated)
+        if replicated is not None else None)
+    return sh, rep
+
+
+def fused_state_placements(mesh: Mesh, restart_axis: str = "restart",
+                           model_axis: str = "model"):
+    """NamedShardings for a restart-STACKED ``DistState`` (a leading (R,)
+    axis on every leaf) on a restart x data x model mesh — the initial
+    placement of the ``fused_restart_sharded`` plan: restarts split over
+    ``restart_axis``, centers over ``model_axis``, everything replicated
+    over the data axes (the dataset itself is placed separately,
+    row-sharded over data)."""
+    r, m = restart_axis, model_axis
+    spec = DistState(pts=P(r, m, None, None), coef=P(r, m, None),
+                     head=P(r, m), sqnorm=P(r, m), counts=P(r, m),
+                     step=P(r))
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec,
+                        is_leaf=lambda s: isinstance(s, P))
 
 
 def shard_dataset(x: jax.Array, mesh: Mesh,

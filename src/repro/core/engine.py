@@ -102,7 +102,7 @@ def _fit_restarts(x: jax.Array, kernel: KernelFn, cfg: MBConfig,
     eval_idx = sample_batch(k_eval, n, eb)
 
     if mesh is not None:
-        from repro.launch.sharding import restart_placements
+        from repro.core.distributed import restart_placements
         ax = _restart_axis_of(mesh, restart_axis)
         if restarts % mesh.shape[ax]:
             raise ValueError(

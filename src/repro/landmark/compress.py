@@ -280,7 +280,7 @@ def grow_window(state: CenterState, extra: int) -> CenterState:
     write head, preserving ring order) — the no-eviction "unbounded
     stream" mode: nothing is ever truncated, so serving cost grows
     linearly with fit history.  This is the baseline the ``compress``
-    axis bounds (benchmarks/run.py ``landmark``); the Algorithm-2 step
+    axis bounds (``tests/test_landmark.py``); the Algorithm-2 step
     reads W from the state shape, so fitting continues unchanged (at the
     cost of a per-growth recompile)."""
     if extra <= 0:

@@ -1,10 +1,7 @@
 """Compiled-program analysis (HLO memory / cost / collective bytes).
 
-Extracted from :mod:`repro.launch.dryrun` so in-process callers —
-``KernelKMeans.explain(deep=True)`` and ``serve --dry-run`` — can analyze
-a compiled step program WITHOUT dryrun's import-time side effect (it
-forces ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before
-jax loads, which is only correct for a dedicated subprocess).
+Used in-process by ``KernelKMeans.explain(deep=True)`` and
+``serve --dry-run`` to analyze a compiled step program.
 """
 from __future__ import annotations
 
@@ -49,9 +46,7 @@ def collective_bytes_of(hlo_text: str) -> dict:
 
 
 def analyze_compiled(compiled) -> dict:
-    """Memory / cost / collective summary of one ``jax`` Compiled object —
-    the per-cell analysis body of ``launch.dryrun.run_cell``, reusable on
-    any compiled program."""
+    """Memory / cost / collective summary of one ``jax`` Compiled object."""
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):   # older jax: one dict per device

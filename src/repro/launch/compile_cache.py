@@ -1,8 +1,8 @@
 """Where the persistent JAX compilation cache lives.
 
-Entry points (``chip_smoke.py``, ``python -m repro.launch.serve``,
-``python -m benchmarks.run``) call :func:`enable_compile_cache` once at
-start-up; importing the library never touches the cache.
+Entry points (``chip_smoke.py``, ``python -m repro.launch.serve``) call
+:func:`enable_compile_cache` once at start-up; importing the library never
+touches the cache.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this module
   sets no other directory.

@@ -2,9 +2,8 @@
 threading the mesh through every call signature.
 
 Model code calls `constrain(x, 'model', None, ...)`; when a mesh has been
-installed (dry-run / production launchers) this becomes a
-with_sharding_constraint, otherwise it is a no-op (single-device smoke
-tests)."""
+installed (`use_mesh`) this becomes a with_sharding_constraint, otherwise
+it is a no-op (single-device smoke tests)."""
 from __future__ import annotations
 
 import contextlib

@@ -1,6 +1,6 @@
-"""Production meshes.  Defined as FUNCTIONS so importing this module never
-touches jax device state (the dry-run process must set XLA_FLAGS before any
-jax initialization)."""
+"""Meshes for the clustering plans.  Defined as FUNCTIONS so importing this
+module never touches jax device state (a process that wants virtual CPU
+devices must set XLA_FLAGS before any jax initialization)."""
 from __future__ import annotations
 
 import jax
@@ -22,20 +22,6 @@ def auto_axes(mesh: Mesh) -> Mesh:
     caller built some other way through this once."""
     return Mesh(mesh.devices, mesh.axis_names, axis_types=_auto(
         len(mesh.axis_names)))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """v5e pod meshes: (16, 16) = 256 chips single pod;
-    (2, 16, 16) = 512 chips across 2 pods."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model: int = 2):
-    """Small CPU mesh for tests (uses however many devices exist)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
 
 
 def make_cluster_mesh(model: int = 1):

@@ -1,9 +1,6 @@
-"""Serving launcher: batched prefill + decode loop, plus the clustering
-serving path (multi-restart fit -> sharded assignment of large query sets)
-and the always-on service demo (repro.service learner/actor split).
-
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --reduced \
-        --batch 4 --prompt-len 32 --gen 16
+"""Clustering serving launcher: the multi-restart fit -> sharded assignment
+of large query sets, the Gram tile cache demo, plan inspection, and the
+always-on service demo (repro.service learner/actor split).
 
     # clustering: fit best-of-R on-device, then serve sharded predictions
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -28,58 +25,10 @@ import jax
 import jax.numpy as jnp
 
 
-def serve_lm(args):
-    from repro.configs import get_config
-    from repro.models import decode_step, init_params, prefill
-
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced(dtype="float32")
-    if cfg.is_encoder:
-        raise SystemExit(f"{cfg.name} is encoder-only: no decode serving "
-                         "(see DESIGN.md skip notes)")
-
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    b, s = args.batch, args.prompt_len
-    key = jax.random.PRNGKey(args.seed + 1)
-    if cfg.frontend == "stub":
-        batch = {"embeds": jax.random.normal(key, (b, s, cfg.frontend_dim))}
-    else:
-        batch = {"tokens": jax.random.randint(key, (b, s), 0, cfg.vocab)}
-
-    cache_len = s + args.gen + 8
-    t0 = time.time()
-    logits, cache = prefill(params, cfg, batch, cache_len=cache_len)
-    logits.block_until_ready()
-    t_prefill = time.time() - t0
-
-    dstep = jax.jit(lambda p, c, t, q: decode_step(p, cfg, c, t, q),
-                    donate_argnums=(1,))
-    tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-    out = [tok]
-    t0 = time.time()
-    for i in range(args.gen - 1):
-        pos = jnp.full((b,), s + i, jnp.int32)
-        logits, cache = dstep(params, cache, tok, pos)
-        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.time() - t0
-
-    gen = jnp.concatenate(out, axis=1)
-    print(f"arch={cfg.name} batch={b} prompt={s} generated={args.gen}")
-    print(f"prefill: {t_prefill * 1e3:.1f} ms "
-          f"({b * s / t_prefill:.0f} tok/s)")
-    print(f"decode:  {t_decode * 1e3:.1f} ms "
-          f"({b * (args.gen - 1) / max(t_decode, 1e-9):.0f} tok/s)")
-    print("sample token ids:", gen[0, :10].tolist())
-
-
 def serve_cluster(args):
     """Fit best-of-R through the KernelKMeans estimator (the restart axis
-    device-sharded), then serve sharded batch assignment — the clustering
-    analogue of prefill+decode: one expensive fit, then high-throughput
-    predict over query shards.
+    device-sharded), then serve sharded batch assignment: one expensive
+    fit, then high-throughput predict over query shards.
 
     With ``--snapshot PATH`` the fit is skipped entirely: the estimator
     is rebuilt from a published snapshot (``KernelKMeans.load`` — the
@@ -302,15 +251,10 @@ def main():
 
     enable_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     # clustering serving path
     ap.add_argument("--cluster", action="store_true",
-                    help="serve kernel k-means assignments instead of an LM")
+                    help="fit, then serve kernel k-means assignments")
     ap.add_argument("--snapshot", default=None,
                     help="serve --cluster from this saved snapshot "
                          "(KernelKMeans.load) instead of refitting "
@@ -380,9 +324,7 @@ def main():
     if args.cluster:
         serve_cluster(args)
         return
-    if args.arch is None:
-        raise SystemExit("--arch is required unless --cluster is given")
-    serve_lm(args)
+    ap.error("choose a mode: --cluster, --cache, --dry-run or --service")
 
 
 if __name__ == "__main__":

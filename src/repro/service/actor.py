@@ -14,7 +14,7 @@ to a small fixed set of shapes means the warmup pass compiles each bucket
 ONCE and steady-state serving recompiles NOTHING — the actor counts its
 own trace-time compiles (``serve_compiles``), and together with the PR-5
 ``program_builds()`` counter this is the "zero recompiles after warmup"
-gate of BENCH_service.json.
+gate of ``tests/test_service.py``.
 
 Snapshot swap: a dedicated swapper thread polls the store; a new version
 is loaded and WARMED (one padded predict per bucket) entirely OFF the

@@ -9,7 +9,7 @@ service's injection sites, and every firing decision is a pure function
 of ``(plan seed, site, occurrence index)`` — never of wall clock, thread
 interleaving, or prior RNG state.  Running the same plan against the
 same deterministic workload twice produces the same fault trace twice
-(``BENCH_chaos.json`` asserts exactly this).
+(``tests/test_faults.py`` asserts exactly this).
 
 Sites (each component fires its site at one well-defined point; with
 ``faults=None`` — the default everywhere — the injection points are
@@ -47,7 +47,8 @@ Kinds:
   reseed paths, Tang & Monteleoni's degenerate-batch instability).
 
 See docs/robustness.md for the recovery guarantee each site+kind pair
-exercises, and ``benchmarks/run.py --only chaos`` for the soak harness.
+exercises; ``tests/test_faults.py`` runs the faults one at a time and
+together, under an actor soak.
 """
 from __future__ import annotations
 

@@ -24,10 +24,10 @@ stream, and converge to a carry BIT-IDENTICAL to an uninterrupted run
 The fixed buffer capacity keeps the resume program's shapes constant, so
 the PR-5 cross-executor program cache compiles it once —
 ``program_builds()`` stays flat across rounds (gated by
-BENCH_service.json).  The per-round early stop (``epsilon`` on the
-round's improvement) is the mini-batch termination bound of Schwartzman
-(arXiv:2304.00419): O(1) iterations per round suffice for normalized
-kernels at b = Theta(log n).
+``tests/test_service.py::test_learner_program_builds_flat_after_round_one``).
+The per-round early stop (``epsilon`` on the round's improvement) is the
+mini-batch termination bound of Schwartzman (arXiv:2304.00419): O(1)
+iterations per round suffice for normalized kernels at b = Theta(log n).
 """
 from __future__ import annotations
 

@@ -32,7 +32,7 @@ Sections for components you did not pass are ``None`` — consumers key on
 presence, not on argument plumbing.  ``fit_builds`` is always present: it
 is the PR-5 cross-executor compile counter
 (:func:`repro.api.executors.program_builds`), the "zero recompiles after
-warmup" gate of BENCH_service.json.
+warmup" counter that ``tests/test_service.py`` holds flat.
 """
 from __future__ import annotations
 

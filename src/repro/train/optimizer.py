@@ -1,8 +1,7 @@
 """AdamW with f32 master weights (params may live in bf16).
 
-State layout (per parameter leaf): master (f32), mu (f32), nu (f32) — all
-sharded like the parameter with the ZeRO upgrade applied by
-launch/sharding.py (first replicated dim additionally sharded over 'data').
+State layout (per parameter leaf): master (f32), mu (f32), nu (f32), each
+shaped like the parameter.
 """
 from __future__ import annotations
 

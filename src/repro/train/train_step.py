@@ -2,8 +2,7 @@
 models, and an int8 error-feedback gradient-compression hook.
 
 The step is pure and pjit-friendly: distribution comes entirely from the
-shardings of TrainState/batch (launch/sharding.py), so the same function
-serves the 1-device smoke tests and the 512-chip dry-run.
+shardings of TrainState/batch.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Shared test fixtures.  NOTE: XLA_FLAGS device-count tricks are deliberately
-NOT set here — smoke tests and benches must see the 1 real CPU device; only
-launch/dryrun.py (its own process) forces 512 placeholder devices."""
+NOT set here — the main pytest process sees the 1 real CPU device; the
+multi-device tests start subprocesses that set it themselves."""
 import numpy as np
 import pytest
 

@@ -179,6 +179,60 @@ def test_fit_cached_matches_fit(use_pallas):
     assert s["hits"] > 0 and s["hit_rate"] > 0.5
 
 
+@pytest.fixture(scope="module")
+def covered_cache_run():
+    """A cached fit (nested sampler) and one cached predict pass over a
+    4x repeated-row query stream, with a capacity that covers every row
+    block (n / tile = 16 blocks, 16 slots)."""
+    from repro.api import KernelKMeans, SolverConfig
+    from repro.core.state import window_size
+    from repro.data import blobs
+
+    n, d, k, b, tau, iters = 2048, 16, 8, 256, 64, 10
+    tile, capacity, reps = n // 16, 16, 4
+    x, _ = blobs(n=n, d=d, k=k, seed=0)
+    x = jnp.asarray(x)
+    kern = Gaussian(kappa=jnp.float32(1.0))
+    est = KernelKMeans(SolverConfig(
+        k=k, batch_size=b, tau=tau, max_iters=iters, epsilon=-1.0,
+        kernel=kern, cache="lru", cache_tile=tile, cache_capacity=capacity,
+        sampler="nested", distribution="single", jit=False))
+    est.fit(x, jax.random.PRNGKey(0),
+            init_idx=jnp.arange(k, dtype=jnp.int32) * (n // k))
+    qidx = jnp.tile(jnp.arange(n, dtype=jnp.int32), reps)
+    labels, ck = predict_cached(est.cache_, est.state_, qidx)
+    return dict(x=x, kern=kern, st=est.state_, qidx=qidx, labels=labels,
+                stats=stats(ck.cache), n=n, tile=tile, capacity=capacity,
+                steps=len(est.history_), b=b, k=k, w=window_size(b, tau))
+
+
+def test_covering_capacity_never_evicts(covered_cache_run):
+    """With capacity >= n / tile no block is ever evicted, so every
+    read-through access after a block's warm is a hit and the counted
+    misses are the complete kernel-evaluation count."""
+    r = covered_cache_run
+    assert r["capacity"] >= r["n"] // r["tile"]
+    assert r["stats"]["evictions"] == 0
+
+
+def test_cached_predict_labels_match_direct_predict(covered_cache_run):
+    r = covered_cache_run
+    direct = predict(r["st"], r["x"], r["x"][r["qidx"]], r["kern"])
+    np.testing.assert_array_equal(np.asarray(r["labels"]),
+                                  np.asarray(direct))
+
+
+def test_cached_evals_below_uncached_count(covered_cache_run):
+    """Counted kernel evaluations of the cached fit + predict fall below
+    the analytic uncached count: per step b*kW assignment + k*W^2
+    recompute + b*kW direct evaluation, per query kW."""
+    r = covered_cache_run
+    kw = r["k"] * r["w"]
+    uncached = (r["steps"] * (2 * r["b"] * kw + r["k"] * r["w"] ** 2)
+                + int(r["qidx"].shape[0]) * kw)
+    assert 0 < r["stats"]["evals"] < uncached
+
+
 def test_predict_cached_counters_all_hits_when_warm():
     kern = Gaussian(kappa=jnp.float32(1.0))
     x = _data(64, 4, seed=7)

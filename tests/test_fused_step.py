@@ -396,6 +396,47 @@ def test_sharded_host_prefetch_bit_identical():
     assert off.history_ == on.history_
 
 
+# ----------------------------------------- one step, fused vs composed
+@pytest.fixture(scope="module")
+def gate_steps():
+    """One Algorithm-2 step of each implementation at an assignment-heavy
+    shape (k=32, b=512, tau=64, W=576): the compiled temp bytes of each
+    program and its outputs from the same state and batch."""
+    from repro.core.minibatch import MBConfig, make_step, sample_batch
+    from repro.core.state import init_state, window_size
+
+    n, d, k, b, tau = 4096, 32, 32, 512, 64
+    x = _blobs(n=n, d=d, k=16)
+    init_idx = (jnp.arange(k, dtype=jnp.int32) * 17) % n
+    bidx = sample_batch(jax.random.PRNGKey(0), n, b)
+    st0 = init_state(x, init_idx, GAUSS, window_size(b, tau))
+    out = {}
+    for impl in ("composed", "fused"):
+        cfg = MBConfig(k=k, batch_size=b, tau=tau, max_iters=5,
+                       epsilon=-1.0, step=impl)
+        step = jax.jit(make_step(GAUSS, cfg))
+        compiled = step.lower(st0, x, bidx).compile()
+        out[impl] = (compiled.memory_analysis().temp_size_in_bytes,
+                     compiled(st0, x, bidx))
+    return out
+
+
+def test_fused_step_temp_below_composed(gate_steps):
+    """The fused step never materializes the (b, k*W) strip, so its
+    compiled program needs fewer temp bytes than the composed chain."""
+    assert gate_steps["fused"][0] < gate_steps["composed"][0]
+
+
+def test_fused_step_bit_identical_to_composed_at_f32(gate_steps):
+    (st_c, info_c), (st_f, info_f) = (gate_steps["composed"][1],
+                                      gate_steps["fused"][1])
+    np.testing.assert_array_equal(np.asarray(st_c.sqnorm),
+                                  np.asarray(st_f.sqnorm))
+    np.testing.assert_array_equal(np.asarray(st_c.idx), np.asarray(st_f.idx))
+    np.testing.assert_array_equal(np.asarray(info_c.improvement),
+                                  np.asarray(info_f.improvement))
+
+
 # ------------------------------------- program cache / donation signatures
 def test_repeated_fit_reuses_one_executable():
     """Donation audit regression: a FRESH estimator of the same config on

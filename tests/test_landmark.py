@@ -368,3 +368,70 @@ except ImportError:
     ])
     def test_drift_bounded_across_cycles(m, seed):
         _drift_cycle_check(m, seed)
+
+
+# ------------------------------------- round-cadence compression vs growth
+@pytest.fixture(scope="module")
+def stream_arms():
+    """Two arms over the same batch schedule from the same init: the
+    ``grow_window`` no-eviction baseline widens every ring by ``grow``
+    slots a round; the compressed arm keeps W and projects every center
+    onto m landmarks after each round."""
+    n, d, k, b, tau = 8192, 16, 8, 128, 64
+    rounds, iters, m, grow, nq = 6, 6, 32, 96, 2048
+    x = jnp.asarray(blobs(n=n, d=d, k=k, seed=0)[0])
+    xe = jnp.asarray(blobs(n=nq, d=d, k=k, seed=1)[0])   # held out
+    w0 = window_size(b, tau)
+    init_idx = (jnp.arange(k, dtype=jnp.int32) * 31) % n
+    step = jax.jit(make_step(GAUSS, MBConfig(k=k, batch_size=b, tau=tau,
+                                             max_iters=iters,
+                                             epsilon=-1.0)))
+    compress = jax.jit(lambda st: compress_state(
+        GAUSS, st, CompressSpec(every=0, m=m), x=x)[0])
+    key = jax.random.PRNGKey(42)
+
+    def run_round(st, rnd):
+        for i in range(iters):
+            st, _ = step(st, x, sample_batch(
+                jax.random.fold_in(key, rnd * iters + i), n, b))
+        return st
+
+    grown, compressed = [], []
+    st_u = init_state(x, init_idx, GAUSS, w0)
+    st_c = init_state(x, init_idx, GAUSS, w0)
+    for rnd in range(rounds):
+        st_u = run_round(st_u, rnd)
+        grown.append(st_u)
+        if rnd < rounds - 1:
+            st_u = grow_window(st_u, grow)
+        st_c = compress(run_round(st_c, rnd))
+        compressed.append(st_c)
+    return dict(x=x, xe=xe, k=k, m=m, grown=grown, compressed=compressed)
+
+
+def test_compressed_objective_within_5pct_on_held_out(stream_arms):
+    a = stream_arms
+    x, m = a["x"], a["m"]
+    su, sc = a["grown"][-1], a["compressed"][-1]
+    obj_u = float(jnp.mean(jnp.min(_dists(
+        su.coef, su.sqnorm, x[su.idx.reshape(-1)], a["xe"]), axis=1)))
+    obj_c = float(jnp.mean(jnp.min(_dists(
+        sc.coef[:, :m], sc.sqnorm, x[sc.idx[:, :m].reshape(-1)], a["xe"]),
+        axis=1)))
+    assert abs(obj_c - obj_u) <= 0.05 * abs(obj_u)
+
+
+def test_compressed_support_flat_while_grown_support_grows(stream_arms):
+    """The serving support of the compressed arm stays at m live rows a
+    center (k*m in all) in every round; the baseline's ring widens and
+    its live rows grow."""
+    a = stream_arms
+    k, m = a["k"], a["m"]
+    for st in a["compressed"]:
+        coef = np.asarray(st.coef)
+        assert np.all(coef[:, m:] == 0)
+        assert np.count_nonzero(coef) <= k * m
+    rows = [st.idx.size for st in a["grown"]]
+    assert all(r1 > r0 for r0, r1 in zip(rows, rows[1:]))
+    live = [np.count_nonzero(np.asarray(st.coef)) for st in a["grown"]]
+    assert live[-1] > live[0] and live[-1] > k * m

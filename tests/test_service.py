@@ -217,6 +217,19 @@ def test_actor_keeps_model_on_stale_snapshot(tmp_path):
     assert actor.version == v0 + 2 and not actor.stale
 
 
+def test_learner_program_builds_flat_after_round_one(tmp_path):
+    """Round 1 fits from scratch and round 2 builds the resume program;
+    the fixed buffer shape keeps every later round on those programs."""
+    from repro.api.executors import program_builds
+
+    learner, *_ = _svc(tmp_path, publish_every=2)
+    builds = []
+    learner.on_round = lambda r: builds.append(program_builds())
+    learner.run(4)
+    assert len(builds) == 4
+    assert builds[1:] == [builds[1]] * 3
+
+
 # ------------------------------------------------------------------ actor
 def test_actor_microbatch_matches_direct(tmp_path):
     """Ragged concurrent requests, coalesced and padded to buckets, must
